@@ -7,6 +7,8 @@ for the full (slow) grids; default quick mode finishes on a laptop CPU.
 benchmarks/baseline.py; schema validated by ``repro.obs.schema``).
 Set ``REPRO_TRACE=trace.jsonl`` to additionally export the span/event
 trace (per-step |R_t|, jit-cache, backend selection) as JSON lines.
+A table module that raises is reported on stdout and makes the run exit
+with status 1 once the remaining modules have run.
 
   bench_nfe           -> Tables 7/8  (avg NFE vs T, Theorem D.1)
   bench_speed         -> Fig. 1/4    (wall-clock scaling in steps)
@@ -88,6 +90,7 @@ def main() -> None:
     print(f"# engine methods: {', '.join(available_methods())}",
           file=sys.stderr)
     print("name,us_per_call,derived")
+    failed = []
     for name in MODULES:
         if name not in only:
             continue
@@ -99,9 +102,14 @@ def main() -> None:
                 print(r, flush=True)
             print(f"# {name} done in {time.time() - t0:.1f}s", flush=True)
         except Exception as e:  # noqa: BLE001
+            failed.append(name)
             print(f"# {name} FAILED: {type(e).__name__}: {e}", flush=True)
             traceback.print_exc(file=sys.stderr)
+    if failed:
+        raise SystemExit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

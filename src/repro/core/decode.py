@@ -15,8 +15,11 @@ samplers) — behind three interchangeable backends:
   * ``"reference"`` — pure jnp (fast on CPU, the correctness oracle).
 
 ``backend="auto"`` (the default everywhere) resolves to ``"pallas"`` on
-TPU and ``"reference"`` elsewhere; set ``REPRO_DECODE_BACKEND`` to force
-a specific backend process-wide.
+TPU and ``"reference"`` elsewhere.  Off the TPU, ``REPRO_DECODE_BACKEND``
+forces a backend process-wide (``interpret`` drives the kernels on CPU).
+On the TPU it may only say ``pallas``: the serving path never falls back
+to the interpreter or the reference there behind the caller's back.  An
+explicit ``backend=`` argument is always honoured.
 
 Decode modes follow ``SamplerConfig.x0_mode``: ``"argmax"`` picks the
 highest adjusted logit; ``"sample"`` draws categorically via the
@@ -43,11 +46,15 @@ BACKENDS = ("pallas", "interpret", "reference")
 
 def default_backend() -> str:
     env = os.environ.get("REPRO_DECODE_BACKEND", "").strip()
-    backend = env or ("pallas" if jax.default_backend() == "tpu"
-                      else "reference")
+    on_tpu = jax.default_backend() == "tpu"
+    backend = env or ("pallas" if on_tpu else "reference")
     if backend not in BACKENDS:
         raise ValueError(f"REPRO_DECODE_BACKEND={env!r}; pick one of "
                          f"{BACKENDS}")
+    if on_tpu and backend != "pallas":
+        raise ValueError(f"REPRO_DECODE_BACKEND={env!r} on a TPU: the decode "
+                         "kernels are compiled there; unset it, or pass "
+                         "backend= explicitly")
     return backend
 
 

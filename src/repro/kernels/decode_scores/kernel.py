@@ -77,15 +77,17 @@ def _decode_scores_kernel(*refs, nk: int, bkv: int, temperature: float,
 
     @pl.when(ik == nk - 1)
     def _flush():
-        tok_ref[0] = sel_idx[...]
-        score_ref[0] = a_tok[...] - (lse_m[...] + jnp.log(lse_s[...]))
+        tok_ref[0] = sel_idx[...][None]
+        score_ref[0] = (a_tok[...] - (lse_m[...] + jnp.log(lse_s[...])))[None]
 
 
 def decode_scores_kernel(logits, mask, gumbel=None, *,
                          temperature: float = 1.0, block_n: int = 256,
                          block_v: int = 1024, interpret: bool = True):
     """logits: (B,N,K); mask: (1,K) f32; gumbel: optional (B,N,K) f32.
-    Returns (tokens (B,N) int32, scores (B,N) f32)."""
+    Returns (tokens (B,1,N) int32, scores (B,1,N) f32): the unit middle
+    axis keeps the output blocks ``(1, 1, bn)`` legal for Mosaic's (8, 128)
+    tiling (see ``dndm_update.kernel``)."""
     B, N, K = logits.shape
     bn = min(block_n, N)
     bkv = min(block_v, K)
@@ -103,7 +105,7 @@ def decode_scores_kernel(logits, mask, gumbel=None, *,
     in_specs.append(pl.BlockSpec((1, bkv), lambda b, i, k: (0, k)))
     args.append(mask)
 
-    out_spec = pl.BlockSpec((1, bn), lambda b, i, k: (b, i))
+    out_spec = pl.BlockSpec((1, 1, bn), lambda b, i, k: (b, 0, i))
     return pl.pallas_call(
         functools.partial(_decode_scores_kernel, nk=nk, bkv=bkv,
                           temperature=temperature,
@@ -111,8 +113,8 @@ def decode_scores_kernel(logits, mask, gumbel=None, *,
         grid=(B, nn, nk),
         in_specs=in_specs,
         out_specs=(out_spec, out_spec),
-        out_shape=(jax.ShapeDtypeStruct((B, N), jnp.int32),
-                   jax.ShapeDtypeStruct((B, N), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((B, 1, N), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1, N), jnp.float32)),
         scratch_shapes=[
             pltpu.VMEM((bn,), jnp.float32),     # running selection max
             pltpu.VMEM((bn,), jnp.int32),       # running argmax

@@ -1,8 +1,8 @@
 """jit'd wrapper for the streaming (token, score) decode.
 
-Mirrors ``dndm_update.ops``: pads N and K up to TPU-friendly block
-multiples (8-sublane / 128-lane granularity) instead of raising on
-non-divisible shapes, and auto-detects the execution backend — compiled
+Mirrors ``dndm_update.ops``: pads N and K up to block multiples
+(``dndm_update.ops.block_sizes``) instead of raising on non-divisible
+shapes, and auto-detects the execution backend — compiled
 Mosaic on TPU, the Pallas interpreter elsewhere (``interpret=None``, the
 default).  Pass ``interpret`` explicitly to force either mode.
 """
@@ -13,8 +13,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.decode_scores.kernel import decode_scores_kernel
-from repro.kernels.dndm_update.ops import (_round_up, default_interpret,
+from repro.kernels.dndm_update.ops import (_round_up, block_sizes,
                                            record_padding)
 
 
@@ -29,8 +30,7 @@ def decode_scores(logits, *, mask=None, gumbel=None,
     if interpret is None:
         interpret = default_interpret()
     B, N, K = logits.shape
-    bn = min(block_n, _round_up(N, 8))
-    bkv = min(block_v, _round_up(K, 128))
+    bn, bkv = block_sizes(N, K, block_n, block_v, interpret)
     pad_n = _round_up(N, bn) - N
     pad_k = _round_up(K, bkv) - K
     record_padding("decode_scores", N, K, pad_n, pad_k)
@@ -53,4 +53,4 @@ def decode_scores(logits, *, mask=None, gumbel=None,
     tok, score = decode_scores_kernel(logits, mask, gumbel=gumbel,
                                       temperature=temperature, block_n=bn,
                                       block_v=bkv, interpret=interpret)
-    return tok[:, :N], score[:, :N]
+    return tok[:, 0, :N], score[:, 0, :N]

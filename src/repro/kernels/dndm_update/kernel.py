@@ -62,7 +62,7 @@ def _dndm_kernel(*refs, nk: int, bkv: int, version: int,
 
     @pl.when(ik == nk - 1)
     def _flush():
-        x = x_ref[0]
+        x = x_ref[0]                                    # (1, bn)
         tau = tau_ref[0]
         t = t_ref[0]
         cond = (tau == t) if version == 1 else (tau >= t)
@@ -73,8 +73,14 @@ def dndm_update_kernel(logits, mask, x, tau, t, gumbel=None, *,
                        version: int = 1, temperature: float = 1.0,
                        block_n: int = 256, block_v: int = 1024,
                        interpret: bool = True):
-    """logits: (B,N,K); mask: (1,K) f32; x, tau: (B,N) int32; t: (1,) int32;
-    gumbel: optional (B,N,K) f32.  Returns updated tokens (B,N) int32."""
+    """logits: (B,N,K); mask: (1,K) f32; x, tau: (B,1,N) int32; t: (1,)
+    int32; gumbel: optional (B,N,K) f32.  Returns updated tokens (B,1,N)
+    int32.
+
+    The per-token operands carry a unit middle axis so that their blocks
+    are ``(1, 1, bn)``: Mosaic tiles the last two block dimensions by
+    (8, 128) unless they span the whole array axis, and a ``(1, bn)``
+    block over a (B, N) array breaks that rule for every B > 1."""
     B, N, K = logits.shape
     bn = min(block_n, N)
     bkv = min(block_v, K)
@@ -91,8 +97,8 @@ def dndm_update_kernel(logits, mask, x, tau, t, gumbel=None, *,
         args.append(gumbel)
     in_specs += [
         pl.BlockSpec((1, bkv), lambda b, i, k: (0, k)),
-        pl.BlockSpec((1, bn), lambda b, i, k: (b, i)),
-        pl.BlockSpec((1, bn), lambda b, i, k: (b, i)),
+        pl.BlockSpec((1, 1, bn), lambda b, i, k: (b, 0, i)),
+        pl.BlockSpec((1, 1, bn), lambda b, i, k: (b, 0, i)),
         pl.BlockSpec((1,), lambda b, i, k: (0,)),
     ]
     args += [mask, x, tau, t]
@@ -103,8 +109,8 @@ def dndm_update_kernel(logits, mask, x, tau, t, gumbel=None, *,
                           has_gumbel=gumbel is not None),
         grid=(B, nn, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bn), lambda b, i, k: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, bn), lambda b, i, k: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, N), jnp.int32),
         scratch_shapes=[
             pltpu.VMEM((bn,), jnp.float32),
             pltpu.VMEM((bn,), jnp.int32),

@@ -1,7 +1,7 @@
 """jit'd wrapper for the fused DNDM decode-update.
 
-Pads N and K up to TPU-friendly block multiples (8-sublane / 128-lane
-granularity) instead of raising on non-divisible shapes, and auto-detects
+Pads N and K up to block multiples (see ``block_sizes``) instead of
+raising on non-divisible shapes, and auto-detects
 the execution backend: compiled Mosaic on TPU, the Pallas interpreter
 elsewhere (``interpret=None``, the default).  Pass ``interpret`` explicitly
 to force either mode.
@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.kernels import default_interpret
 from repro.kernels.dndm_update.kernel import dndm_update_kernel
 
 
@@ -37,9 +38,22 @@ def record_padding(kernel: str, N: int, K: int, pad_n: int,
               ).set(round(1.0 - (N * K) / total, 6), kernel=kernel)
 
 
-def default_interpret() -> bool:
-    """Compiled on TPU, interpret everywhere else."""
-    return jax.default_backend() != "tpu"
+def block_sizes(N: int, K: int, block_n: int, block_v: int,
+                interpret: bool) -> tuple[int, int]:
+    """(token block, vocab block) for a streaming decode kernel call.
+
+    The token block is the lane dimension of the (B, 1, N) per-token
+    operands and the sublane dimension of the (B, N, K) logit blocks; the
+    vocab block is the logits' lane dimension.  Compiled Mosaic needs a
+    lane block that is a multiple of 128 or the whole padded axis, so the
+    caller's sizes are rounded up to that outside interpret mode.  The
+    interpreter takes them as given (small multi-block grids in tests).
+    """
+    n8, k128 = _round_up(N, 8), _round_up(K, 128)
+    if interpret:
+        return min(block_n, n8), min(block_v, k128)
+    bn = _round_up(block_n, 128)
+    return (n8 if n8 <= bn else bn), min(_round_up(block_v, 128), k128)
 
 
 @partial(jax.jit, static_argnames=("version", "block_n", "block_v",
@@ -55,8 +69,7 @@ def dndm_update(logits, x, tau, t, *, mask=None, gumbel=None,
     if interpret is None:
         interpret = default_interpret()
     B, N, K = logits.shape
-    bn = min(block_n, _round_up(N, 8))
-    bkv = min(block_v, _round_up(K, 128))
+    bn, bkv = block_sizes(N, K, block_n, block_v, interpret)
     pad_n = _round_up(N, bn) - N
     pad_k = _round_up(K, bkv) - K
     record_padding("dndm_update", N, K, pad_n, pad_k)
@@ -78,9 +91,9 @@ def dndm_update(logits, x, tau, t, *, mask=None, gumbel=None,
         if gumbel is not None:
             gumbel = jnp.pad(gumbel, ((0, 0), (0, 0), (0, pad_k)))
     t_arr = jnp.asarray(t, jnp.int32).reshape(1)
-    out = dndm_update_kernel(logits, mask, x.astype(jnp.int32),
-                             tau.astype(jnp.int32), t_arr,
+    out = dndm_update_kernel(logits, mask, x.astype(jnp.int32)[:, None],
+                             tau.astype(jnp.int32)[:, None], t_arr,
                              gumbel=gumbel, version=version,
                              temperature=temperature, block_n=bn,
                              block_v=bkv, interpret=interpret)
-    return out[:, :N]
+    return out[:, 0, :N]

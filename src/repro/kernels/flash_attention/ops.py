@@ -3,6 +3,7 @@
 Accepts the model's (B, S, H, hd) layout, reorders to the kernel's
 (B, H, S, hd), and pads sequence lengths up to block multiples (padded
 keys are masked with NEG bias; padded queries are sliced off).
+``interpret=None`` compiles on TPU and interprets elsewhere.
 """
 from __future__ import annotations
 
@@ -11,14 +12,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import NEG, flash_attention_kernel
 
 
 @partial(jax.jit, static_argnames=("block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, bias, *, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = True):
+                    block_k: int = 256, interpret: bool | None = None):
     """q,k,v: (B,S,H,hd) (kv heads already repeated); bias: (B,Sq,Sk).
     Returns (B,Sq,H,hd)."""
+    if interpret is None:
+        interpret = default_interpret()
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     bq, bk = min(block_q, Sq), min(block_k, Sk)

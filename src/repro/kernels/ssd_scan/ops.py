@@ -1,5 +1,6 @@
 """jit'd wrapper: model layout (B,S,H,P) -> kernel layout, padding to
-chunk multiples, slicing the result back."""
+chunk multiples, slicing the result back.  ``interpret=None`` compiles on
+TPU and interprets elsewhere."""
 from __future__ import annotations
 
 from functools import partial
@@ -7,14 +8,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan_kernel
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dtv, A, Bm, Cm, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """x: (B,S,H,P); dtv: (B,S,H); A: (H,); Bm/Cm: (B,S,N).
     Returns (y (B,S,H,P), None) matching the chunked-ref signature."""
+    if interpret is None:
+        interpret = default_interpret()
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     L = min(chunk, S)

@@ -1,5 +1,5 @@
 """Serving launcher: load a checkpoint (or init), build the generation
-engine on the local mesh, drain a batch of synthetic requests.
+engine, drain a batch of synthetic requests.
 
     PYTHONPATH=src python -m repro.launch.serve --arch dndm-text8 \
         --reduced --requests 16 --method dndm_topk_static
@@ -10,12 +10,30 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 
 import repro.configs as configs_lib
 from repro.core.samplers import registry
 from repro.models.model import Model
 from repro.serving import BatchScheduler, EngineConfig, GenerationEngine
 from repro.training import checkpoint
+
+
+def build_engine(arch: str = "dndm-text8", *, reduced: bool = False,
+                 ckpt: str = "", seed: int = 0,
+                 **engine_kw) -> GenerationEngine:
+    """The serving engine for ``arch``: params from ``ckpt``, or random
+    from ``PRNGKey(seed)``; ``engine_kw`` fills :class:`EngineConfig`."""
+    cfg = configs_lib.get(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    cfg = cfg.replace(bidirectional=True)
+    model = Model(cfg)
+    if ckpt:
+        params = jax.tree.map(jnp.asarray, checkpoint.load(ckpt))
+    else:
+        params = model.init(jax.random.PRNGKey(seed))
+    return GenerationEngine(model, params, EngineConfig(**engine_kw))
 
 
 def main():
@@ -37,20 +55,10 @@ def main():
     ap.add_argument("--len", type=int, default=64)
     args = ap.parse_args()
 
-    cfg = configs_lib.get(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    cfg = cfg.replace(bidirectional=True)
-    model = Model(cfg)
-    if args.ckpt:
-        import jax.numpy as jnp
-        params = jax.tree.map(jnp.asarray, checkpoint.load(args.ckpt))
-    else:
-        params = model.init(jax.random.PRNGKey(0))
-
-    engine = GenerationEngine(model, params, EngineConfig(
-        method=args.method, steps=args.steps, nfe_budget=args.nfe_budget,
-        noise_kind=args.noise_kind))
+    engine = build_engine(args.arch, reduced=args.reduced, ckpt=args.ckpt,
+                          method=args.method, steps=args.steps,
+                          nfe_budget=args.nfe_budget,
+                          noise_kind=args.noise_kind)
     sched = BatchScheduler(engine, max_batch=args.max_batch,
                            bucket_len=args.len)
     t0 = time.time()
@@ -64,4 +72,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

@@ -1,9 +1,9 @@
 """Distributed training launcher.
 
 On real hardware this builds the production mesh, shards params/optimizer
-with the rule system and runs the pjit train step; on this CPU container
-it runs the same code path over however many devices exist (use
-launch/dryrun.py for the 512-device compile-only validation).
+with the rule system and runs the pjit train step; on a CPU it runs the
+same code path over however many devices exist (use launch/dryrun.py for
+the 512-device compile-only validation).
 
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --reduced --steps 20 --batch 8 --seq 128
@@ -92,4 +92,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

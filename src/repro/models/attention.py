@@ -5,7 +5,7 @@ Three interchangeable inner implementations (``cfg.attn_impl``):
   blocked — online-softmax over KV chunks in pure JAX (lax.scan); the
             memory-bounded TPU-shaped algorithm and the oracle for the
             Pallas flash kernel
-  pallas  — kernels/flash_attention (interpret=True on CPU)
+  pallas  — kernels/flash_attention (compiled on TPU, interpreted elsewhere)
 
 Mask semantics: ``causal`` plus optional ``sliding_window`` (only the last
 W positions visible).  The diffusion denoiser runs with causal=False.

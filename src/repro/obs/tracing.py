@@ -331,36 +331,13 @@ def timeline(request_id: str, path: str | None = None) -> list[dict]:
     return sorted(out, key=lambda r: (r["ts"], r["span_id"]))
 
 
-class _Profile:
-    """jax.profiler.trace wrapper that never breaks the serving path."""
-
-    __slots__ = ("dir", "_cm")
-
-    def __init__(self, dir: str):
-        self.dir = dir
-        self._cm = None
-
-    def __enter__(self):
-        try:
-            import jax
-            self._cm = jax.profiler.trace(self.dir)
-            self._cm.__enter__()
-        except Exception:           # noqa: BLE001 — profiling is best-effort
-            self._cm = None
-        return self
-
-    def __exit__(self, *exc):
-        if self._cm is not None:
-            try:
-                self._cm.__exit__(*exc)
-            except Exception:       # noqa: BLE001
-                pass
-        return False
-
-
 def maybe_jax_profile():
-    """``jax.profiler.trace`` context if ``REPRO_JAX_PROFILE=dir`` is set."""
+    """``jax.profiler.trace`` context if ``REPRO_JAX_PROFILE=dir`` is set.
+
+    A profile that was asked for and cannot start raises on entry, so a
+    missing device trace is never mistaken for an empty one."""
     d = os.environ.get("REPRO_JAX_PROFILE", "").strip()
     if not d:
         return NULL_SPAN
-    return _Profile(d)
+    import jax
+    return jax.profiler.trace(d)

@@ -9,6 +9,13 @@ is immediately servable (``registry.names()`` is the method list).
 For conditional requests, ``cond={"prefix_tokens": src}``: the model
 wrapper feeds [src | x_t] with bidirectional attention and returns target
 logits, so samplers stay prefix-agnostic.
+
+The weights reach every compiled sampler and stepwise call as an
+argument, inside the ``cond`` pytree the samplers pass through untouched
+(:meth:`GenerationEngine.call_cond`).  A denoiser that closed over them
+would bake them into each program as constants: at published widths that
+is hundreds of MB per executable, compiled and held on the device once
+per program.
 """
 from __future__ import annotations
 
@@ -57,7 +64,11 @@ class GenerationEngine:
             from repro.core.noise import multinomial
             self.noise = multinomial(v)
         self.check_method(engine_cfg.method)    # fail fast, list alternatives
-        self.denoise_fn = model.denoise_fn(params)
+        model_fn = model.denoise_fn
+
+        def denoise(x_t, t, c):
+            return model_fn(c["params"])(x_t, t, c["cond"])
+        self.denoise_fn = denoise
         self._law_cache: dict = {}
         self._jit_cache: dict = {}
         self._host_warm: set = set()    # host-sampler per-step jit warm keys
@@ -89,6 +100,11 @@ class GenerationEngine:
                 cdist = trans_lib.beta_continuous(17, 4)
             self._law_cache[lk] = (schedule, dist, cdist)
         return self._law_cache[lk]
+
+    def call_cond(self, cond: dict | None) -> dict:
+        """The ``cond`` argument of a sampler call: the request's own
+        conditioning plus the weights, which ``denoise_fn`` unpacks."""
+        return {"params": self.params, "cond": cond}
 
     def runtime(self) -> registry.SamplerRuntime:
         c = self.cfg
@@ -197,12 +213,12 @@ class GenerationEngine:
                 # step/reveal histograms and decode.* counters on every
                 # jit-cache miss, so obs is suppressed for its duration
                 with obs.suppressed():
-                    warm = spec.run(key, rt, batch, N, cond)
+                    warm = spec.run(key, rt, batch, N, self.call_cond(cond))
                     jax.block_until_ready(warm.tokens)
                 warm_wall = time.time() - tc
                 self._host_warm.add(ck)
             t0 = time.time()
-            out = spec.run(key, rt, batch, N, cond)
+            out = spec.run(key, rt, batch, N, self.call_cond(cond))
             jax.block_until_ready(out.tokens)
             wall = time.time() - t0
             # estimated per-step jit warm-up: cold walk minus steady walk
@@ -219,12 +235,13 @@ class GenerationEngine:
                 tc = time.time()
                 call = jax.jit(
                     lambda k, c: run(k, rt, batch, N, c).tokens,
-                ).lower(key, cond).compile()
+                ).lower(key, self.call_cond(cond)).compile()
                 compile_s = time.time() - tc
                 self._jit_cache[ck] = (call, spec.static_nfe(rt, N))
             call, nfe = self._jit_cache[ck]
             t0 = time.time()        # timed run starts after compilation
-            out = SamplerOutput(tokens=call(key, cond), nfe=nfe,
+            out = SamplerOutput(tokens=call(key, self.call_cond(cond)),
+                                nfe=nfe,
                                 aux={"compile_seconds": compile_s})
             jax.block_until_ready(out.tokens)
             wall = time.time() - t0
@@ -372,8 +389,8 @@ class StepwiseRunner:
                       request_ids=rids):
             state = self.spec.stepwise_step(
                 {"x": self.x, "revealed": self.revealed},
-                self.tau, jnp.asarray(t_row), jnp.asarray(keys), cond,
-                self.rt)
+                self.tau, jnp.asarray(t_row), jnp.asarray(keys),
+                self.engine.call_cond(cond), self.rt)
             self.x, self.revealed = state["x"], state["revealed"]
         self.calls += 1
         if obs.enabled():

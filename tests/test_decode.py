@@ -158,3 +158,18 @@ def test_backend_resolution(monkeypatch):
         decode.default_backend()
     with pytest.raises(ValueError):
         decode.resolve_backend("nope")
+
+
+def test_tpu_refuses_non_kernel_backend_override(monkeypatch):
+    """On a TPU the serving path never drops to the interpreter or the
+    reference behind the caller's back; explicit backend= still works."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_DECODE_BACKEND", raising=False)
+    assert decode.resolve_backend() == "pallas"
+    monkeypatch.setenv("REPRO_DECODE_BACKEND", "pallas")
+    assert decode.resolve_backend() == "pallas"
+    for env in ("interpret", "reference"):
+        monkeypatch.setenv("REPRO_DECODE_BACKEND", env)
+        with pytest.raises(ValueError, match="TPU"):
+            decode.resolve_backend()
+        assert decode.resolve_backend(env) == env

@@ -127,3 +127,25 @@ def test_ssd_chunked_ref_matches_sequential(key):
                                    atol=3e-5, rtol=3e-5)
     np.testing.assert_allclose(np.asarray(s_c), np.asarray(s_seq),
                                atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("N,K,block_n,block_v,want", [
+    (256, 28, 256, 1024, (256, 128)),      # whole padded axes
+    (256, 28, 8, 64, (128, 128)),          # small caller blocks rounded up
+    (1000, 32000, 256, 1024, (256, 1024)),
+    (200, 300, 100, 200, (128, 256)),      # N split into 128-wide blocks
+    (7, 1000, 16, 64, (8, 128)),
+])
+def test_block_sizes_compiled_mode_follows_tiling(N, K, block_n, block_v,
+                                                  want):
+    """Compiled Mosaic: token block a multiple of 128 or the whole padded
+    axis, vocab block a multiple of 128, whatever the caller passes."""
+    bn, bkv = dndm_ops.block_sizes(N, K, block_n, block_v, interpret=False)
+    assert (bn, bkv) == want
+    n8 = -(-N // 8) * 8
+    assert bn % 128 == 0 or bn == n8
+    assert bkv % 128 == 0
+
+
+def test_block_sizes_interpret_mode_keeps_caller_blocks():
+    assert dndm_ops.block_sizes(64, 257, 16, 64, interpret=True) == (16, 64)

@@ -792,3 +792,25 @@ def test_regress_bench_kind_and_mismatched_kinds():
     gone = {"schema": 2, "methods": {}}
     ok, lines = regress.compare(mk(1.0), gone)
     assert not ok
+
+
+def test_jax_profile_off_without_env(monkeypatch):
+    monkeypatch.delenv("REPRO_JAX_PROFILE", raising=False)
+    with obs.maybe_jax_profile() as p:
+        assert p is obs.tracing.NULL_SPAN
+
+
+def test_jax_profile_that_cannot_start_raises(monkeypatch, tmp_path):
+    """A profile asked for with REPRO_JAX_PROFILE either starts or raises
+    — a missing device trace must never pass silently."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def refuse(log_dir):
+        raise RuntimeError("profiler unavailable")
+        yield
+    monkeypatch.setenv("REPRO_JAX_PROFILE", str(tmp_path))
+    monkeypatch.setattr(jax.profiler, "trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with obs.maybe_jax_profile():
+            pass
