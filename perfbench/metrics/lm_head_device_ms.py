@@ -1,0 +1,11 @@
+"""LM head: mean device milliseconds, per execution of the step program in
+the traced window, of the operations under the ``lm_head`` named scope
+(final norm and unembedding; ``progtrace.scope_ms_per_step``)."""
+from perfbench import progtrace
+
+
+def read(ctx):
+    prog = getattr(ctx, "program", None)
+    if getattr(ctx, "trace", None) is None or prog is None:
+        return None
+    return progtrace.scope_ms_per_step(ctx.trace, prog, "lm_head")

@@ -21,6 +21,10 @@ On the TPU it may only say ``pallas``: the serving path never falls back
 to the interpreter or the reference there behind the caller's back.  An
 explicit ``backend=`` argument is always honoured.
 
+Both ops run under ``jax.named_scope("decode")``, as does the stepwise
+path's per-row noise draw, so a device trace charges the noise, the
+padding and the kernel to the decode path.
+
 Decode modes follow ``SamplerConfig.x0_mode``: ``"argmax"`` picks the
 highest adjusted logit; ``"sample"`` draws categorically via the
 Gumbel-max trick (argmax of logits/temp + mask + Gumbel(0,1) noise), so
@@ -102,20 +106,23 @@ def fused_update(key: jax.Array, logits: Array, x: Array, tau: Array, t,
         # compiled program, i.e. "which backend serves this sampler"
         obs.counter("decode.backend_calls").inc(op="fused_update",
                                                 backend=backend)
-    mask = noise.logit_mask(jnp.float32)
-    if gumbel is None:
-        gumbel = _gumbel(key, logits.shape, cfg.x0_mode)
-    t = jnp.asarray(t, jnp.int32)
-    if backend == "reference":
-        out = _ref.dndm_update_ref(logits, x, tau.astype(jnp.int32),
-                                   t.reshape(1), version=version, mask=mask,
-                                   temperature=cfg.temperature,
-                                   gumbel=gumbel)
-        return out.astype(jnp.int32)
-    return _ops.dndm_update(logits, x, tau, t, mask=mask, gumbel=gumbel,
-                            version=version, temperature=cfg.temperature,
-                            block_n=block_n, block_v=block_v,
-                            interpret=(backend == "interpret"))
+    with jax.named_scope("decode"):
+        mask = noise.logit_mask(jnp.float32)
+        if gumbel is None:
+            gumbel = _gumbel(key, logits.shape, cfg.x0_mode)
+        t = jnp.asarray(t, jnp.int32)
+        if backend == "reference":
+            out = _ref.dndm_update_ref(logits, x, tau.astype(jnp.int32),
+                                       t.reshape(1), version=version,
+                                       mask=mask,
+                                       temperature=cfg.temperature,
+                                       gumbel=gumbel)
+            return out.astype(jnp.int32)
+        return _ops.dndm_update(logits, x, tau, t, mask=mask, gumbel=gumbel,
+                                version=version,
+                                temperature=cfg.temperature,
+                                block_n=block_n, block_v=block_v,
+                                interpret=(backend == "interpret"))
 
 
 def decode_tokens(key: jax.Array, logits: Array, noise, cfg, *,
@@ -141,14 +148,15 @@ def decode_tokens(key: jax.Array, logits: Array, noise, cfg, *,
     if obs.enabled():
         obs.counter("decode.backend_calls").inc(op="decode_tokens",
                                                 backend=backend)
-    mask = noise.logit_mask(jnp.float32)
-    if gumbel is None:
-        gumbel = _gumbel(key, logits.shape, cfg.x0_mode)
-    if backend == "reference":
-        return _sref.decode_scores_ref(logits, mask=mask,
-                                       temperature=cfg.temperature,
-                                       gumbel=gumbel)
-    return _sops.decode_scores(logits, mask=mask, gumbel=gumbel,
-                               temperature=cfg.temperature, block_n=block_n,
-                               block_v=block_v,
-                               interpret=(backend == "interpret"))
+    with jax.named_scope("decode"):
+        mask = noise.logit_mask(jnp.float32)
+        if gumbel is None:
+            gumbel = _gumbel(key, logits.shape, cfg.x0_mode)
+        if backend == "reference":
+            return _sref.decode_scores_ref(logits, mask=mask,
+                                           temperature=cfg.temperature,
+                                           gumbel=gumbel)
+        return _sops.decode_scores(logits, mask=mask, gumbel=gumbel,
+                                   temperature=cfg.temperature,
+                                   block_n=block_n, block_v=block_v,
+                                   interpret=(backend == "interpret"))

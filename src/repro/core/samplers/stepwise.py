@@ -183,8 +183,9 @@ def _row_gumbel(keys: Array, shape, x0_mode: str) -> Array | None:
     to the (1, N, K) slab the solo batch-of-one step draws from that key."""
     if x0_mode == "argmax":
         return None
-    return jax.vmap(lambda k: jax.random.gumbel(k, shape[1:],
-                                                jnp.float32))(keys)
+    with jax.named_scope("decode"):
+        return jax.vmap(lambda k: jax.random.gumbel(k, shape[1:],
+                                                    jnp.float32))(keys)
 
 
 def _row_split(keys: Array) -> tuple[Array, Array]:

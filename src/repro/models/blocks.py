@@ -38,16 +38,19 @@ def _attn_init(key, cfg: ModelConfig, is_moe: bool) -> dict:
 
 def _attn_apply(params, x, cfg: ModelConfig, *, causal: bool, window: int,
                 is_moe: bool):
-    h = rmsnorm(params["ln1"], x, cfg.norm_eps)
-    x = x + attention.apply(params["attn"], h, cfg, causal=causal,
-                            window=window)
+    # named scopes: each sub-layer, its pre-norm and residual add
+    with jax.named_scope("attention"):
+        h = rmsnorm(params["ln1"], x, cfg.norm_eps)
+        x = x + attention.apply(params["attn"], h, cfg, causal=causal,
+                                window=window)
     aux = {}
-    h = rmsnorm(params["ln2"], x, cfg.norm_eps)
-    if is_moe:
-        y, aux = moe.apply(params["moe"], h, cfg)
-        x = x + y
-    elif "mlp" in params:
-        x = x + mlp(params["mlp"], h, cfg.mlp_type)
+    with jax.named_scope("mlp"):
+        h = rmsnorm(params["ln2"], x, cfg.norm_eps)
+        if is_moe:
+            y, aux = moe.apply(params["moe"], h, cfg)
+            x = x + y
+        elif "mlp" in params:
+            x = x + mlp(params["mlp"], h, cfg.mlp_type)
     return x, aux
 
 

@@ -1,6 +1,12 @@
 """Top-level Model: embeddings, superblock stack (scan or unrolled),
 diffusion time conditioning, frontend fusion, LM head, KV/state caches.
 
+The forward pass names its parts with ``jax.named_scope``:
+``time_embed``, ``attention`` and ``mlp`` (``models/blocks.py``) and
+``lm_head`` (final norm and unembedding).  The names reach the compiled
+program's op metadata, so a device trace can charge each operation to
+its part of the denoiser.
+
 The block pattern is decomposed into ``unit * n_super`` (config enforces
 periodicity).  Non-shared block weights are stacked along a leading
 ``n_super`` axis and the stack runs as one ``lax.scan`` (fast compiles) or
@@ -69,7 +75,8 @@ class Model:
             causal = not cfg.bidirectional
         h = params["embed"][tokens]
         if t is not None and cfg.time_conditioning:
-            h = h + time_embed(params["time"], t, cfg.d_model)[:, None]
+            with jax.named_scope("time_embed"):
+                h = h + time_embed(params["time"], t, cfg.d_model)[:, None]
         h = frontend.fuse(h, frontend_embeds)
 
         def superblock(h, unit_slice):
@@ -98,9 +105,10 @@ class Model:
                 h, (lb_j, rz_j) = body(h, sl)
                 lb, rz = lb + lb_j, rz + rz_j
 
-        h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
-        logits = h @ (params["embed"].T if cfg.tie_embeddings
-                      else params["head"])
+        with jax.named_scope("lm_head"):
+            h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
+            logits = h @ (params["embed"].T if cfg.tie_embeddings
+                          else params["head"])
         return logits, {"load_balance": lb, "router_z": rz}
 
     # ---------------- diffusion denoiser adapter ----------------

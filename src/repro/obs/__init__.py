@@ -19,8 +19,9 @@ instrumented call site).  Ways to turn it on:
   budgets scored at request completion (:mod:`repro.obs.slo`);
 * ``obs.enable()``            — programmatic, e.g. from tests.
 
-``REPRO_JAX_PROFILE=dir`` additionally wraps every ``engine.generate``
-in ``jax.profiler.trace(dir)`` for device-level TPU traces.
+``REPRO_JAX_PROFILE=dir`` records one ``jax.profiler`` session for the
+whole process into ``dir`` (written at exit): the device trace, with
+every span above on the profiler's clock beside the device's operations.
 
 Every serving-path record carries the request id minted at
 ``submit()``; ``obs.timeline(request_id)`` (optionally with a trace-file
@@ -35,22 +36,22 @@ import os
 from repro.obs import exporter, metrics, sketch, slo, tracing
 from repro.obs.metrics import (counter, disable, enable, enabled, gauge,
                                histogram, reset, snapshot, suppressed)
-from repro.obs.tracing import (event, flush_sink, maybe_jax_profile,
-                               set_sink, span, summary, timeline,
-                               write_metrics_record)
+from repro.obs.tracing import (event, flush_sink, set_sink, span,
+                               summary, timeline, write_metrics_record)
 
 __all__ = [
     "counter", "gauge", "histogram", "snapshot", "reset",
     "enable", "disable", "enabled", "suppressed",
     "span", "event", "summary", "set_sink", "flush_sink", "timeline",
-    "write_metrics_record", "maybe_jax_profile",
+    "write_metrics_record",
     "metrics", "tracing", "sketch", "exporter", "slo",
     "configure_from_env",
 ]
 
 
 def configure_from_env() -> None:
-    """Read REPRO_TRACE / REPRO_METRICS / exporter / SLO env; idempotent."""
+    """Read REPRO_TRACE / REPRO_METRICS / exporter / SLO /
+    REPRO_JAX_PROFILE env; idempotent."""
     trace = os.environ.get("REPRO_TRACE", "").strip()
     port = os.environ.get("REPRO_METRICS_PORT", "").strip()
     snap = os.environ.get("REPRO_SNAPSHOT", "").strip()
@@ -71,6 +72,9 @@ def configure_from_env() -> None:
     spec = os.environ.get("REPRO_SLO", "").strip()
     if spec and not slo.active():
         slo.configure(slo.parse(spec))
+    profile = os.environ.get("REPRO_JAX_PROFILE", "").strip()
+    if profile:
+        tracing.start_profile(profile)
 
 
 configure_from_env()

@@ -14,19 +14,24 @@ in-memory buffer (``records()``/:func:`summary`) and, if a sink is set
 appended to the file as one JSON line.  The export schema is documented
 and validated in :mod:`repro.obs.schema`.
 
-``maybe_jax_profile()`` is the optional device-level hook: when
-``REPRO_JAX_PROFILE=dir`` is set it wraps the region in
-``jax.profiler.trace(dir)`` (TPU/TensorBoard traces); otherwise it is
-the same no-op singleton.
+While a JAX profiler session is recording (``REPRO_JAX_PROFILE=dir``,
+the benchmark's tracer, or any capture an operator starts), every span
+is also a ``jax.profiler.TraceAnnotation`` on the profiler's own clock,
+with its scalar attributes as the event's stats: the device trace then
+says what the host was doing around each device operation.  Spans are
+on when either telemetry or a profiler session is; with neither,
+:func:`span` returns :data:`NULL_SPAN` after the guard.
 """
 from __future__ import annotations
 
 import atexit
 import itertools
 import json
-import os
 import threading
 import time
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from repro.obs import metrics as _metrics
 
@@ -54,6 +59,7 @@ _sink_path: str | None = None
 _sink_buf: list[str] = []
 _sink_last_flush = 0.0
 _sink_lock = threading.Lock()
+_profile_dir: str | None = None     # REPRO_JAX_PROFILE session, if any
 
 
 def _stack() -> list:
@@ -132,43 +138,69 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class Span:
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "ts", "_t0")
+def _scalars(attrs: dict) -> dict:
+    """The attributes a profiler event can carry as stats."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (int, float, str))}
 
-    def __init__(self, name: str, attrs: dict):
+
+class Span:
+    """A span recorded as a JSON-lines record (``record``), as a profiler
+    event (``profile``), or both."""
+
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "ts", "_t0",
+                 "_record", "_annotation")
+
+    def __init__(self, name: str, attrs: dict, record: bool = True,
+                 profile: bool = False):
         self.name = name
         self.attrs = attrs
+        self._record = record
+        self._annotation = (TraceAnnotation(name, **_scalars(attrs))
+                            if profile else None)
 
     def __enter__(self):
-        st = _stack()
-        self.parent_id = st[-1].span_id if st else None
-        self.span_id = _next_id()
-        self.ts = time.time()
-        self._t0 = time.perf_counter()
-        st.append(self)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._record:
+            st = _stack()
+            self.parent_id = st[-1].span_id if st else None
+            self.span_id = _next_id()
+            self.ts = time.time()
+            self._t0 = time.perf_counter()
+            st.append(self)
         return self
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**_scalars(attrs))
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
-        st = _stack()
-        if st and st[-1] is self:
-            st.pop()
-        _emit({"kind": "span", "name": self.name, "ts": self.ts,
-               "span_id": self.span_id, "parent_id": self.parent_id,
-               "dur_s": dur,
-               "attrs": {k: _coerce(v) for k, v in self.attrs.items()}})
+        if self._record:
+            dur = time.perf_counter() - self._t0
+            st = _stack()
+            if st and st[-1] is self:
+                st.pop()
+            _emit({"kind": "span", "name": self.name, "ts": self.ts,
+                   "span_id": self.span_id, "parent_id": self.parent_id,
+                   "dur_s": dur,
+                   "attrs": {k: _coerce(v) for k, v in self.attrs.items()}})
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
 def span(name: str, **attrs):
-    """Timed region; no-op singleton when telemetry is disabled."""
-    if not _metrics.enabled():
+    """Timed region: a JSON-lines record while telemetry is enabled, a
+    profiler event while a profiler session records; otherwise the no-op
+    singleton."""
+    record = _metrics.enabled()
+    profile = TraceAnnotation.is_enabled()
+    if not (record or profile):
         return NULL_SPAN
-    return Span(name, attrs)
+    return Span(name, attrs, record, profile)
 
 
 def event(name: str, **attrs) -> None:
@@ -331,13 +363,31 @@ def timeline(request_id: str, path: str | None = None) -> list[dict]:
     return sorted(out, key=lambda r: (r["ts"], r["span_id"]))
 
 
-def maybe_jax_profile():
-    """``jax.profiler.trace`` context if ``REPRO_JAX_PROFILE=dir`` is set.
+# ------------------------------------------------------------------
+# process-long profiler session (REPRO_JAX_PROFILE)
+# ------------------------------------------------------------------
 
-    A profile that was asked for and cannot start raises on entry, so a
-    missing device trace is never mistaken for an empty one."""
-    d = os.environ.get("REPRO_JAX_PROFILE", "").strip()
-    if not d:
-        return NULL_SPAN
-    import jax
-    return jax.profiler.trace(d)
+def start_profile(log_dir: str) -> None:
+    """Record one JAX profiler session into ``log_dir`` until the process
+    exits (or :func:`stop_profile`).  Every span of the run, the serving
+    path's included, lands in it beside the device's operations.  A
+    session that was asked for and cannot start raises, so a missing
+    device trace is never mistaken for an empty one."""
+    global _profile_dir
+    if _profile_dir == log_dir:
+        return
+    stop_profile()
+    jax.profiler.start_trace(log_dir)
+    _profile_dir = log_dir
+
+
+def stop_profile() -> None:
+    """Stop the session :func:`start_profile` began and write its trace."""
+    global _profile_dir
+    if _profile_dir is None:
+        return
+    _profile_dir = None
+    jax.profiler.stop_trace()
+
+
+atexit.register(stop_profile)

@@ -148,14 +148,13 @@ class GenerationEngine:
 
         With ``repro.obs`` enabled, every call is an ``engine.generate``
         trace span (method/kind/batch/seq + nfe/wall/cache/backend) and
-        feeds the engine.* metrics; ``REPRO_JAX_PROFILE=dir``
-        additionally captures a ``jax.profiler`` device trace.
+        feeds the engine.* metrics.
         """
         m = method or self.cfg.method
         spec = self.check_method(m)
         rt = self.runtime()
         with obs.span("engine.generate", method=m, kind=spec.kind,
-                      batch=batch, seq=N) as sp, obs.maybe_jax_profile():
+                      batch=batch, seq=N) as sp:
             out, wall, cache = self._run(key, spec, m, rt, batch, N, cond)
             if obs.enabled():
                 backend = decode_lib.resolve_backend()
@@ -179,12 +178,16 @@ class GenerationEngine:
         set under ``key`` determines every network call the request will
         ever make (times, per-call key stream, x_T) before sampling
         starts.  The continuous scheduler calls this at ``submit()``.
+
+        The ``engine.plan`` span covers the draw and the device syncs
+        that bring the plan to the host.
         """
         m = method or self.cfg.method
         spec = self.check_method(m)
         if spec.schedule_fn is None:
             raise ValueError(f"{m} does not expose a call schedule")
-        return spec.schedule_fn(key, self.runtime(), N)
+        with obs.span("engine.plan", method=m):
+            return spec.schedule_fn(key, self.runtime(), N)
 
     def stepwise(self, rows: int, N: int, method: str | None = None,
                  prefix_len: int = 0) -> "StepwiseRunner":
@@ -278,6 +281,12 @@ class StepwiseRunner:
     Completed rows are harvested *inside* :meth:`step` (returned as
     ``{row: tokens}``) before any later call can touch the buffer, so
     results are exactly-once by construction.
+
+    ``padded_positions`` counts the positions of the live rows that lie
+    past their request's length: work each call computes and the caller
+    cuts off.  The runner does not know the lengths; the caller that
+    does (``ContinuousScheduler``) keeps the sum at admission and
+    completion, and every ``engine.stepwise`` span reports it.
     """
 
     def __init__(self, engine: GenerationEngine, method: str, rows: int,
@@ -307,6 +316,7 @@ class StepwiseRunner:
         self._plans: list[CallSchedule | None] = [None] * rows
         self._ptr = [0] * rows
         self.calls = 0                          # batched network calls
+        self.padded_positions = 0               # kept by the caller
 
     def free_rows(self) -> list[int]:
         return [i for i in range(self.rows) if self._plans[i] is None]
@@ -329,7 +339,9 @@ class StepwiseRunner:
         required for the tau-consuming methods (the DNDM family) and
         ignored by the schedule-driven baselines (``tau=None`` plans).
         ``prefixes`` (aligned with ``pairs``) is required iff the runner
-        was built with ``prefix_len > 0``.
+        was built with ``prefix_len > 0``.  The ``engine.admit`` span
+        (``rows`` admitted) covers the host stacking, the copies to the
+        device and the dispatch of the scatters.
         """
         if not pairs:
             return
@@ -344,20 +356,22 @@ class StepwiseRunner:
             if plan.x0 is None or plan.step_keys is None:
                 raise ValueError("stepwise admission needs a full plan "
                                  "(x0, step_keys) — see samplers/stepwise")
-        idx = jnp.asarray([row for row, _ in pairs], jnp.int32)
-        x0 = np.stack([np.asarray(p.x0, np.int32).reshape(self.N)
-                       for _, p in pairs])
-        tau = np.stack([
-            np.zeros(self.N, self._t_dtype) if p.tau is None
-            else np.asarray(p.tau, self._t_dtype).reshape(self.N)
-            for _, p in pairs])
-        self.x = self.x.at[idx].set(jnp.asarray(x0))
-        self.revealed = self.revealed.at[idx].set(False)
-        self.tau = self.tau.at[idx].set(jnp.asarray(tau))
-        if self.prefix_len:
-            pre = np.stack([np.asarray(p, np.int32).reshape(self.prefix_len)
-                            for p in prefixes])
-            self.prefix = self.prefix.at[idx].set(jnp.asarray(pre))
+        with obs.span("engine.admit", method=self.method, rows=len(pairs)):
+            idx = jnp.asarray([row for row, _ in pairs], jnp.int32)
+            x0 = np.stack([np.asarray(p.x0, np.int32).reshape(self.N)
+                           for _, p in pairs])
+            tau = np.stack([
+                np.zeros(self.N, self._t_dtype) if p.tau is None
+                else np.asarray(p.tau, self._t_dtype).reshape(self.N)
+                for _, p in pairs])
+            self.x = self.x.at[idx].set(jnp.asarray(x0))
+            self.revealed = self.revealed.at[idx].set(False)
+            self.tau = self.tau.at[idx].set(jnp.asarray(tau))
+            if self.prefix_len:
+                pre = np.stack([
+                    np.asarray(p, np.int32).reshape(self.prefix_len)
+                    for p in prefixes])
+                self.prefix = self.prefix.at[idx].set(jnp.asarray(pre))
         for row, plan in pairs:
             self._plans[row] = plan
             self._ptr[row] = 0
@@ -365,28 +379,36 @@ class StepwiseRunner:
     def step(self) -> dict[int, np.ndarray]:
         """One batched network call; returns tokens of rows that finished.
 
-        With telemetry on, every call is an ``engine.stepwise`` span
-        whose ``request_ids`` attribute lists the trace identity of each
-        row the call advanced (comma-joined) — the per-call backbone of
-        ``obs.timeline(request_id)``.
+        The ``engine.stepwise`` span covers the host's preparation of the
+        call's per-row times and keys and the dispatch of the call, not
+        the device's execution, which runs after the span closes and is
+        timed by the device trace.  Its attributes: ``rows`` live,
+        ``padded_positions`` (see the class docstring) and, with
+        telemetry on, ``request_ids``, the trace identity of each row the
+        call advanced (comma-joined), the per-call backbone of
+        ``obs.timeline(request_id)``.  When a row finishes, the
+        ``engine.harvest`` span (``rows`` finished) covers the copy of the
+        buffer to the host, which waits for the call.
         """
         active = self.active_rows()
         if not active:
             return {}
-        t_row = np.full((self.rows,), self._t_free, self._t_dtype)
-        keys = np.zeros((self.rows, 2), np.uint32)
-        for i in active:
-            plan = self._plans[i]
-            t_row[i] = plan.times[self._ptr[i]]
-            keys[i] = plan.step_keys[self._ptr[i]]
-        cond = (None if self.prefix is None
-                else {"prefix_tokens": self.prefix})
-        rids = (",".join(p.request_id for i in active
-                         if (p := self._plans[i]).request_id is not None)
-                if obs.enabled() else "")
-        with obs.span("engine.stepwise", method=self.method,
-                      call=self.calls, rows=len(active),
-                      request_ids=rids):
+        attrs = {"method": self.method, "call": self.calls,
+                 "rows": len(active),
+                 "padded_positions": self.padded_positions}
+        if obs.enabled():
+            attrs["request_ids"] = ",".join(
+                p.request_id for i in active
+                if (p := self._plans[i]).request_id is not None)
+        with obs.span("engine.stepwise", **attrs):
+            t_row = np.full((self.rows,), self._t_free, self._t_dtype)
+            keys = np.zeros((self.rows, 2), np.uint32)
+            for i in active:
+                plan = self._plans[i]
+                t_row[i] = plan.times[self._ptr[i]]
+                keys[i] = plan.step_keys[self._ptr[i]]
+            cond = (None if self.prefix is None
+                    else {"prefix_tokens": self.prefix})
             state = self.spec.stepwise_step(
                 {"x": self.x, "revealed": self.revealed},
                 self.tau, jnp.asarray(t_row), jnp.asarray(keys),
@@ -398,14 +420,17 @@ class StepwiseRunner:
         done: dict[int, np.ndarray] = {}
         finished = [i for i in active
                     if self._ptr[i] + 1 == len(self._plans[i].times)]
-        if finished:
+        for i in active:
+            self._ptr[i] += 1
+        if not finished:
+            return done
+        with obs.span("engine.harvest", method=self.method,
+                      rows=len(finished)):
             # one transfer of the whole buffer: cheaper than per-row
             # device slices, and the sync point keeps the dispatch queue
             # shallow on CPU
             host_x = np.asarray(jax.device_get(self.x))
-        for i in active:
-            self._ptr[i] += 1
-            if self._ptr[i] == len(self._plans[i].times):
+            for i in finished:
                 done[i] = host_x[i].copy()
                 self._plans[i] = None
         return done

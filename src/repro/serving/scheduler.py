@@ -264,7 +264,12 @@ class ContinuousScheduler:
 
     def submit(self, length: int, prefix: np.ndarray | None = None,
                method: str | None = None) -> int:
-        """Enqueue a request; its call schedule is sampled *now*."""
+        """Enqueue a request; its call schedule is sampled *now*.
+
+        Drawing the request's key and plan is one ``scheduler.submit``
+        span (``request_id``, ``method``, ``length``, ``mode``,
+        ``planned_nfe``); the plan's draw and its device syncs are the
+        ``engine.plan`` span inside it."""
         if length > self.bucket_len:
             raise ValueError(f"length {length} > bucket_len "
                              f"{self.bucket_len}")
@@ -279,17 +284,17 @@ class ContinuousScheduler:
             prefix = np.asarray(prefix, np.int32).reshape(-1)
         r = Request(self._rid, length, prefix, method)
         r.request_id = mint_request_id()
-        r.key = jax.random.fold_in(self._key, self._rid)
-        # stamp the trace identity onto the plan: the StepwiseRunner
-        # reads it back to label every batched call this request rides
-        r.plan = dataclasses.replace(
-            self.engine.plan_request(r.key, self.bucket_len, method),
-            request_id=r.request_id)
-        r.t_submit = time.time()
-        if obs.enabled():
-            obs.event("scheduler.submit", request_id=r.request_id,
-                      method=method, length=length, mode="continuous",
-                      planned_nfe=r.plan.nfe)
+        with obs.span("scheduler.submit", request_id=r.request_id,
+                      method=method, length=length,
+                      mode="continuous") as sp:
+            r.key = jax.random.fold_in(self._key, self._rid)
+            # stamp the trace identity onto the plan: the StepwiseRunner
+            # reads it back to label every batched call this request rides
+            r.plan = dataclasses.replace(
+                self.engine.plan_request(r.key, self.bucket_len, method),
+                request_id=r.request_id)
+            r.t_submit = time.time()
+            sp.set(planned_nfe=r.plan.nfe)
         self.queue.append(r)
         return self._rid
 
@@ -324,6 +329,8 @@ class ContinuousScheduler:
         runner.admit_many(
             [(row, r.plan) for row, r in placed],
             [r.prefix for _, r in placed] if group[1] else None)
+        runner.padded_positions += sum(self.bucket_len - r.length
+                                       for _, r in placed)
         t_admit = time.time()
         for row, r in placed:
             self._row_req[(group, row)] = r
@@ -369,6 +376,10 @@ class ContinuousScheduler:
         Returns True while work remains (queued or in flight).  Drive it
         from a serving loop interleaved with ``submit()`` calls; ``run()``
         below pumps to completion for synchronous use.
+
+        Inside the ``scheduler.pump`` span the runner's ``engine.admit``,
+        ``engine.stepwise`` and ``engine.harvest`` spans say where the
+        host's time goes; what is left is the scheduler's bookkeeping.
         """
         group = self._next_group()
         if group is None:
@@ -389,6 +400,7 @@ class ContinuousScheduler:
             t_done = time.time()
             for row, toks in finished.items():
                 r = self._row_req.pop((group, row))
+                runner.padded_positions -= self.bucket_len - r.length
                 r.result = toks[: r.length]
                 r.nfe = r.plan.nfe
                 r.steps_executed = r.plan.steps_executed

@@ -123,6 +123,7 @@ def test_span_nesting_and_export_schema(telemetry, tmp_path):
 
 def test_null_span_when_disabled():
     assert not obs.enabled()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
     sp = obs.span("nope", a=1)
     assert sp is obs.tracing.NULL_SPAN
     with sp as s:
@@ -795,22 +796,133 @@ def test_regress_bench_kind_and_mismatched_kinds():
 
 
 def test_jax_profile_off_without_env(monkeypatch):
+    """Without REPRO_JAX_PROFILE no profiler session starts, and with
+    telemetry off too every span is the no-op singleton."""
     monkeypatch.delenv("REPRO_JAX_PROFILE", raising=False)
-    with obs.maybe_jax_profile() as p:
-        assert p is obs.tracing.NULL_SPAN
+    obs.configure_from_env()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert obs.span("x", a=1) is obs.tracing.NULL_SPAN
 
 
 def test_jax_profile_that_cannot_start_raises(monkeypatch, tmp_path):
     """A profile asked for with REPRO_JAX_PROFILE either starts or raises
     — a missing device trace must never pass silently."""
-    import contextlib
-
-    @contextlib.contextmanager
-    def refuse(log_dir):
+    def refuse(log_dir, *a, **k):
         raise RuntimeError("profiler unavailable")
-        yield
     monkeypatch.setenv("REPRO_JAX_PROFILE", str(tmp_path))
-    monkeypatch.setattr(jax.profiler, "trace", refuse)
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
     with pytest.raises(RuntimeError, match="profiler unavailable"):
-        with obs.maybe_jax_profile():
-            pass
+        obs.configure_from_env()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+
+
+# ------------------------------------------------------------------
+# spans on the profiler's clock
+# ------------------------------------------------------------------
+
+SERVING_SPANS = ("scheduler.submit", "engine.plan", "scheduler.pump",
+                 "engine.admit", "engine.stepwise", "engine.harvest")
+
+
+def _host_events(log_dir, names) -> list[tuple[str, float, float, dict]]:
+    """(name, start_ns, end_ns, stats) of the host events named in
+    ``names`` in the one trace under ``log_dir``, in start order."""
+    import glob
+
+    from jax.profiler import ProfileData
+    files = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    assert len(files) == 1, files
+    out = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out.extend((e.name, e.start_ns, e.end_ns, dict(e.stats))
+                       for e in line.events if e.name in names)
+    return sorted(out, key=lambda ev: ev[1])
+
+
+def test_span_on_profiler_clock(telemetry, tmp_path):
+    """While a profiler session records, a span is also a profiler event
+    whose stats are its scalar attributes, those set mid-span included;
+    the JSON-lines record is written as before."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("outer", rows=3, method="dndm", skip=[1]) as sp:
+            sp.set(padded_positions=5)
+    finally:
+        jax.profiler.stop_trace()
+    (ev,) = _host_events(tmp_path, {"outer"})
+    assert ev[3] == {"rows": 3, "method": "dndm", "padded_positions": 5}
+    (rec,) = [r for r in obs.tracing.records() if r["name"] == "outer"]
+    assert rec["attrs"]["padded_positions"] == 5
+
+
+def test_serving_spans_in_profile(monkeypatch, tmp_path, tiny):
+    """REPRO_JAX_PROFILE traces the serving path with telemetry off:
+    scheduler.submit holds engine.plan, scheduler.pump holds
+    engine.admit / engine.stepwise / engine.harvest, and their rows and
+    padded-position stats are the ones computed here from the requests'
+    plans."""
+    model, params = tiny
+    eng = GenerationEngine(model, params, EngineConfig(
+        method="dndm", steps=STEPS, shared_tau=False))
+    sched = ContinuousScheduler(eng, max_batch=2, bucket_len=SEQ, seed=3)
+    lengths = {sched.submit(5): 5, sched.submit(SEQ): SEQ}
+    sched.pump()                        # compile outside the profile
+    sched = ContinuousScheduler(eng, max_batch=2, bucket_len=SEQ, seed=3)
+    monkeypatch.setenv("REPRO_JAX_PROFILE", str(tmp_path))
+    assert not obs.enabled()
+    obs.configure_from_env()
+    try:
+        rids = [sched.submit(n) for n in lengths.values()]
+        done = sched.run()
+    finally:
+        obs.tracing.stop_profile()
+    evs = _host_events(tmp_path, set(SERVING_SPANS))
+
+    def inside(inner, outer):
+        return [[o for o in evs if o[0] == outer
+                 and o[1] <= e[1] and e[2] <= o[2]]
+                for e in evs if e[0] == inner]
+    assert len(inside("engine.plan", "scheduler.submit")) == 2
+    for inner, outer in (("engine.plan", "scheduler.submit"),
+                         ("engine.admit", "scheduler.pump"),
+                         ("engine.stepwise", "scheduler.pump"),
+                         ("engine.harvest", "scheduler.pump")):
+        assert all(len(c) == 1 for c in inside(inner, outer)), inner
+
+    nfe = {rid: done[rid].plan.nfe for rid in rids}
+    length = dict(zip(rids, lengths.values()))
+    calls = max(nfe.values())
+    submits = [e[3] for e in evs if e[0] == "scheduler.submit"]
+    assert [(s["length"], s["planned_nfe"]) for s in submits] == [
+        (length[r], nfe[r]) for r in rids]
+    assert [e[3]["rows"] for e in evs if e[0] == "engine.admit"] == [2]
+    steps = [e[3] for e in evs if e[0] == "engine.stepwise"]
+    assert [(s["rows"], s["padded_positions"]) for s in steps] == [
+        (sum(j < nfe[r] for r in rids),
+         sum(SEQ - length[r] for r in rids if j < nfe[r]))
+        for j in range(calls)]
+    harvests = [e[3]["rows"] for e in evs if e[0] == "engine.harvest"]
+    want = [sum(nfe[r] == j + 1 for r in rids) for j in range(calls)]
+    assert harvests == [n for n in want if n]
+
+
+def test_step_program_carries_named_scopes(tiny):
+    """The denoiser's parts and the decode path are named in the step
+    program's debug info, from which the compiler's op metadata (and so
+    a device trace) takes them."""
+    import re
+
+    from repro.core.samplers import stepwise
+    eng = _engine(tiny)
+    rt = eng.runtime()
+    x = np.zeros((2, SEQ), np.int32)
+    lowered = stepwise._dndm_rows.lower(
+        x, x + 1, np.array([1, 2], np.int32), np.zeros((2, 2), np.uint32),
+        eng.call_cond(None), denoise_fn=rt.denoise_fn, noise=rt.noise,
+        cfg=rt.cfg, version=1, T=rt.dist.T)
+    text = lowered.as_text(debug_info=True)
+    for scope in ("time_embed", "attention", "mlp", "lm_head", "decode"):
+        assert re.search(rf'loc\("([^"]*/)?{scope}/', text), scope
