@@ -57,7 +57,7 @@ from repro.core.samplers.dndm_topk import _reveal_topk
 Array = jnp.ndarray
 
 
-@dataclasses.dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True, eq=False)
 class CallSchedule:
     """One request's predetermined network-call schedule.
 
@@ -77,6 +77,9 @@ class CallSchedule:
     span lists the ids of the rows it advanced — which is what makes a
     request's full call timeline reconstructable from one trace file
     (``obs.timeline``).  ``schedule_fn`` implementations leave it None.
+
+    Plans compare and hash by identity: a runner hands each finished
+    canvas back under the plan of the request it belongs to.
     """
 
     times: np.ndarray                    # descending call times
@@ -114,7 +117,11 @@ def dndm_plan(key: jax.Array, rt, N: int) -> CallSchedule:
                                 order=rt.order, shared=rt.shared_tau)
     tau_row = np.asarray(jax.device_get(tau))[0]
     times = loop.unique_times(tau_row)
-    step_keys = np.asarray(jax.random.split(k_loop, len(times)))
+    # one split of fixed length T, cut on the host: under
+    # jax_threefry_partitionable (on by default) a split's first n keys do
+    # not depend on its length, so this is split(k_loop, len(times)), and
+    # one program for every request whatever its call count
+    step_keys = np.asarray(jax.random.split(k_loop, rt.dist.T))[:len(times)]
     return CallSchedule(times=times, T=rt.dist.T, tau=tau_row,
                         x0=np.asarray(jax.device_get(x))[0],
                         step_keys=step_keys)

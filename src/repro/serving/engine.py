@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,16 @@ class EngineConfig:
     ddim_stride: int = 1              # DDIM baseline subsequence stride
 
 
+def host_device() -> jax.Device:
+    """The host's CPU device, where requests' keys and plans are drawn.
+
+    A plan is a handful of small programs and a copy to the host; drawn
+    on the accelerator, its first read waits for every call queued there
+    and the programs then run one at a time with the accelerator idle.
+    Raises when JAX has no CPU backend: there is no fallback."""
+    return jax.devices("cpu")[0]
+
+
 class GenerationEngine:
     def __init__(self, model: Model, params, engine_cfg: EngineConfig):
         self.model = model
@@ -72,6 +83,9 @@ class GenerationEngine:
         self._law_cache: dict = {}
         self._jit_cache: dict = {}
         self._host_warm: set = set()    # host-sampler per-step jit warm keys
+        # batched stepwise calls dispatched by every runner of this
+        # engine: the clock of a deferred harvest's ``lag_calls``
+        self.stepwise_dispatched = 0
 
     def check_method(self, name: str) -> registry.SamplerSpec:
         """Resolve a method and validate it against the engine's noise
@@ -179,15 +193,21 @@ class GenerationEngine:
         ever make (times, per-call key stream, x_T) before sampling
         starts.  The continuous scheduler calls this at ``submit()``.
 
-        The ``engine.plan`` span covers the draw and the device syncs
-        that bring the plan to the host.
+        The draw runs on the host's CPU device (:func:`host_device`),
+        whatever device ``key`` is on, and the plan comes back as host
+        arrays, so planning never waits for the calls queued on the
+        accelerator.  Every request of a method runs the same fixed set
+        of CPU programs.  The ``engine.plan`` span (``device``) covers
+        the draw and its copies to the host.
         """
         m = method or self.cfg.method
         spec = self.check_method(m)
         if spec.schedule_fn is None:
             raise ValueError(f"{m} does not expose a call schedule")
-        with obs.span("engine.plan", method=m):
-            return spec.schedule_fn(key, self.runtime(), N)
+        rt, cpu = self.runtime(), host_device()
+        with obs.span("engine.plan", method=m, device=cpu.platform), \
+                jax.default_device(cpu):
+            return spec.schedule_fn(jax.device_put(key, cpu), rt, N)
 
     def stepwise(self, rows: int, N: int, method: str | None = None,
                  prefix_len: int = 0) -> "StepwiseRunner":
@@ -278,9 +298,18 @@ class StepwiseRunner:
     padded and per-row solo parity is preserved).  Free rows hold the
     noise pad token.
 
-    Completed rows are harvested *inside* :meth:`step` (returned as
-    ``{row: tokens}``) before any later call can touch the buffer, so
-    results are exactly-once by construction.
+    A request's last call is known from its plan, so its row is freed
+    when that call is dispatched and is re-admittable at the next step
+    boundary.  Its canvas, the output of that call, is read one call
+    later: after the next call of any runner of the engine has been
+    dispatched, so the read waits only for the request's own last call
+    while the next one runs, and the accelerator's queue never drains at
+    a request's turnover.  Results come back keyed by the request's
+    plan, not its row, which may already hold the next request; each
+    finished canvas is read once, so results are exactly-once.  Before a
+    call is dispatched the host waits for the call two back, never the
+    one before: one call in flight hides one copy, and the host runs at
+    most two calls ahead of the device.
 
     ``padded_positions`` counts the positions of the live rows that lie
     past their request's length: work each call computes and the caller
@@ -315,6 +344,12 @@ class StepwiseRunner:
                                 jnp.int32) if prefix_len else None)
         self._plans: list[CallSchedule | None] = [None] * rows
         self._ptr = [0] * rows
+        # rows whose last call ran but whose canvas is unread: that
+        # call's output canvas, the engine's dispatch count after it, and
+        # the (row, plan) pairs it finished
+        self._unread: tuple[jax.Array, int,
+                            list[tuple[int, CallSchedule]]] | None = None
+        self._recent: deque = deque(maxlen=2)   # last two calls' outputs
         self.calls = 0                          # batched network calls
         self.padded_positions = 0               # kept by the caller
 
@@ -323,6 +358,12 @@ class StepwiseRunner:
 
     def active_rows(self) -> list[int]:
         return [i for i in range(self.rows) if self._plans[i] is not None]
+
+    def unread_rows(self) -> list[int]:
+        """Rows whose request's last call was dispatched but whose canvas
+        has not been read; each may already hold the next request."""
+        return [] if self._unread is None else [i for i, _ in
+                                                self._unread[2]]
 
     def admit(self, row: int, plan: CallSchedule,
               prefix: np.ndarray | None = None) -> None:
@@ -338,6 +379,8 @@ class StepwiseRunner:
         Plans must carry (x0, step_keys); ``tau`` is additionally
         required for the tau-consuming methods (the DNDM family) and
         ignored by the schedule-driven baselines (``tau=None`` plans).
+        Finished canvases come back keyed by plan, so each request needs
+        a plan object of its own.
         ``prefixes`` (aligned with ``pairs``) is required iff the runner
         was built with ``prefix_len > 0``.  The ``engine.admit`` span
         (``rows`` admitted) covers the host stacking, the copies to the
@@ -376,8 +419,12 @@ class StepwiseRunner:
             self._plans[row] = plan
             self._ptr[row] = 0
 
-    def step(self) -> dict[int, np.ndarray]:
-        """One batched network call; returns tokens of rows that finished.
+    def step(self) -> dict[CallSchedule, np.ndarray]:
+        """One batched network call; returns the whole canvas row of each
+        request whose last call was an earlier one, keyed by its plan.
+
+        With no live row and canvases unread (a drain), no call is
+        dispatched and the canvases are read at once.
 
         The ``engine.stepwise`` span covers the host's preparation of the
         call's per-row times and keys and the dispatch of the call, not
@@ -386,13 +433,14 @@ class StepwiseRunner:
         ``padded_positions`` (see the class docstring) and, with
         telemetry on, ``request_ids``, the trace identity of each row the
         call advanced (comma-joined), the per-call backbone of
-        ``obs.timeline(request_id)``.  When a row finishes, the
-        ``engine.harvest`` span (``rows`` finished) covers the copy of the
-        buffer to the host, which waits for the call.
+        ``obs.timeline(request_id)``.  The read is the ``engine.harvest``
+        span (see :meth:`harvest`).
         """
         active = self.active_rows()
         if not active:
-            return {}
+            return self.harvest()
+        if len(self._recent) == self._recent.maxlen:
+            self._recent[0].block_until_ready()     # call k-1, never k
         attrs = {"method": self.method, "call": self.calls,
                  "rows": len(active),
                  "padded_positions": self.padded_positions}
@@ -414,23 +462,50 @@ class StepwiseRunner:
                 self.tau, jnp.asarray(t_row), jnp.asarray(keys),
                 self.engine.call_cond(cond), self.rt)
             self.x, self.revealed = state["x"], state["revealed"]
+        self._recent.append(self.x)
         self.calls += 1
+        self.engine.stepwise_dispatched += 1
         if obs.enabled():
             obs.counter("engine.stepwise_calls").inc(method=self.method)
-        done: dict[int, np.ndarray] = {}
-        finished = [i for i in active
-                    if self._ptr[i] + 1 == len(self._plans[i].times)]
+        ready, self._unread = self._unread, None
+        finished = []
         for i in active:
             self._ptr[i] += 1
-        if not finished:
-            return done
-        with obs.span("engine.harvest", method=self.method,
-                      rows=len(finished)):
-            # one transfer of the whole buffer: cheaper than per-row
-            # device slices, and the sync point keeps the dispatch queue
-            # shallow on CPU
-            host_x = np.asarray(jax.device_get(self.x))
-            for i in finished:
-                done[i] = host_x[i].copy()
+            if self._ptr[i] == len(self._plans[i].times):
+                finished.append((i, self._plans[i]))
                 self._plans[i] = None
+        if finished:
+            self.x.copy_to_host_async()
+            self._unread = (self.x, self.engine.stepwise_dispatched,
+                            finished)
+        return self._read(ready)
+
+    def harvest(self) -> dict[CallSchedule, np.ndarray]:
+        """Read the unread canvases now: the whole canvas row of each
+        request whose last call was dispatched, keyed by its plan.
+
+        The scheduler calls it after another runner's call has been
+        dispatched; :meth:`step` calls it at a drain.  The
+        ``engine.harvest`` span covers the read, which waits for the
+        request's last call, and the copies.  Its attributes: ``rows``
+        read and ``lag_calls``, the calls of any runner of the engine
+        dispatched after that last call (1 in a steady stream, 0 at a
+        drain)."""
+        ready, self._unread = self._unread, None
+        return self._read(ready)
+
+    def _read(self, ready) -> dict[CallSchedule, np.ndarray]:
+        if ready is None:
+            return {}
+        canvas, seq, finished = ready
+        lag = self.engine.stepwise_dispatched - seq
+        with obs.span("engine.harvest", method=self.method,
+                      rows=len(finished), lag_calls=lag):
+            # one transfer of the whole buffer: cheaper than per-row
+            # device slices
+            host_x = np.asarray(canvas)
+            done = {plan: host_x[i].copy() for i, plan in finished}
+        if lag and obs.enabled():
+            obs.counter("engine.harvests_deferred").inc(
+                len(finished), method=self.method)
         return done

@@ -34,7 +34,8 @@ import numpy as np
 
 from repro import obs
 from repro.obs import slo as slo_lib
-from repro.serving.engine import GenerationEngine, StepwiseRunner
+from repro.serving.engine import (GenerationEngine, StepwiseRunner,
+                                  host_device)
 
 # process-wide request-id mint: ids stay unique across scheduler
 # instances so one trace file can hold several schedulers' requests
@@ -235,6 +236,12 @@ class ContinuousScheduler:
     ``request.key`` regardless (same tau set, same per-step key stream;
     see ``samplers/stepwise.py`` for the parity contract).
 
+    Each request's tokens come back on the pump after its last call: the
+    runner frees the row when that call is dispatched and reads the
+    canvas after the next call (see
+    :class:`~repro.serving.engine.StepwiseRunner`), so nothing on the
+    turnover path waits for the accelerator.
+
     Requests are grouped by (method, prefix length) — every registered
     method has a stepwise step, and conditional (prefix) requests get a
     conditional runner per exact prefix length, so prefixes are never
@@ -254,21 +261,30 @@ class ContinuousScheduler:
         self.queue: list[Request] = []
         self.done: dict[int, Request] = {}
         self._rid = 0
-        self._key = jax.random.PRNGKey(seed)
+        with jax.default_device(host_device()):
+            self._key = jax.random.PRNGKey(seed)
+            # compile the per-request key derivation now: on the first
+            # submit its CPU compile (0.2-0.4 s) would stall serving
+            jax.random.fold_in(self._key, 0)
         # group = (method, prefix_len); 0 = unconditional
         self._runners: dict[tuple, StepwiseRunner] = {}
         self._rotation: list[tuple] = []    # groups in first-seen order
         self._rr = 0                        # round-robin cursor
         self._row_req: dict[tuple, Request] = {}  # (group, row) -> request
+        # requests whose last call was dispatched, canvas not yet read
+        self._retired: dict = {}                  # plan -> request
         self.total_calls = 0        # aggregate NFE: batched network calls
 
     def submit(self, length: int, prefix: np.ndarray | None = None,
                method: str | None = None) -> int:
         """Enqueue a request; its call schedule is sampled *now*.
 
-        Drawing the request's key and plan is one ``scheduler.submit``
-        span (``request_id``, ``method``, ``length``, ``mode``,
-        ``planned_nfe``); the plan's draw and its device syncs are the
+        The key and the plan are drawn on the host's CPU device, so a
+        submit never waits for the calls queued on the accelerator; the
+        key stays there (``Request.key``, uncommitted: a solo replay
+        moves it to the default device).  Drawing them is one
+        ``scheduler.submit`` span (``request_id``, ``method``,
+        ``length``, ``mode``, ``planned_nfe``); the plan's draw is the
         ``engine.plan`` span inside it."""
         if length > self.bucket_len:
             raise ValueError(f"length {length} > bucket_len "
@@ -287,7 +303,8 @@ class ContinuousScheduler:
         with obs.span("scheduler.submit", request_id=r.request_id,
                       method=method, length=length,
                       mode="continuous") as sp:
-            r.key = jax.random.fold_in(self._key, self._rid)
+            with jax.default_device(host_device()):
+                r.key = jax.random.fold_in(self._key, self._rid)
             # stamp the trace identity onto the plan: the StepwiseRunner
             # reads it back to label every batched call this request rides
             r.plan = dataclasses.replace(
@@ -349,12 +366,13 @@ class ContinuousScheduler:
     def _next_group(self) -> tuple | None:
         """The next group with work, round-robin from the cursor.
 
-        Work = live rows in the group's runner or queued requests of the
-        group.  New groups join the rotation in first-arrival order; the
-        cursor only ever advances one served group at a time, so no group
-        with work is passed over twice before every other one is served
-        — the fairness bound a steady single-method stream used to
-        violate by pinning the old ``self._current`` forever.
+        Work = live or unread rows in the group's runner or queued
+        requests of the group.  New groups join the rotation in
+        first-arrival order; the cursor only ever advances one served
+        group at a time, so no group with work is passed over twice
+        before every other one is served — the fairness bound a steady
+        single-method stream used to violate by pinning the old
+        ``self._current`` forever.
         """
         for r in self.queue:
             g = self._group(r)
@@ -364,7 +382,8 @@ class ContinuousScheduler:
         for off in range(n):
             g = self._rotation[(self._rr + off) % n]
             runner = self._runners.get(g)
-            if ((runner is not None and runner.active_rows())
+            if ((runner is not None
+                 and (runner.active_rows() or runner.unread_rows()))
                     or any(self._group(r) == g for r in self.queue)):
                 self._rr = (self._rr + off + 1) % n
                 return g
@@ -373,9 +392,14 @@ class ContinuousScheduler:
     def pump(self) -> bool:
         """Serve ONE group: admit what fits, issue one batched call.
 
-        Returns True while work remains (queued or in flight).  Drive it
-        from a serving loop interleaved with ``submit()`` calls; ``run()``
-        below pumps to completion for synchronous use.
+        Returns True while work remains (queued, in flight or unread).
+        Drive it from a serving loop interleaved with ``submit()`` calls;
+        ``run()`` below pumps to completion for synchronous use.
+
+        A request completes on the pump after its last call, whichever
+        group that pump serves: once the pump's call is dispatched, the
+        canvases every other runner holds unread are read too.  At a
+        drain the last pump dispatches no call and only reads.
 
         Inside the ``scheduler.pump`` span the runner's ``engine.admit``,
         ``engine.stepwise`` and ``engine.harvest`` spans say where the
@@ -395,12 +419,17 @@ class ContinuousScheduler:
                     method=group[0])
                 sp.set(queue_depth=len(self.queue),
                        live_rows=len(runner.active_rows()))
+            calls = runner.calls
             finished = runner.step()
-            self.total_calls += 1
+            if runner.calls > calls:
+                self.total_calls += 1
+                self._retire(group, runner)
+                for other in self._runners.values():
+                    if other is not runner and other.unread_rows():
+                        finished.update(other.harvest())
             t_done = time.time()
-            for row, toks in finished.items():
-                r = self._row_req.pop((group, row))
-                runner.padded_positions -= self.bucket_len - r.length
+            for plan, toks in finished.items():
+                r = self._retired.pop(plan)
                 r.result = toks[: r.length]
                 r.nfe = r.plan.nfe
                 r.steps_executed = r.plan.steps_executed
@@ -421,7 +450,18 @@ class ContinuousScheduler:
                         r.method, latency_s=t_done - r.t_admit,
                         queue_s=r.t_admit - r.t_submit, nfe=r.nfe)
                 self.done[r.rid] = r
-        return bool(self.queue or self._row_req)
+        return bool(self.queue or self._row_req or self._retired)
+
+    def _retire(self, group: tuple, runner: StepwiseRunner) -> None:
+        """Free the rows of ``group`` whose last call was just dispatched:
+        their requests wait for their canvas under their plan, and their
+        padding leaves the runner's count."""
+        live = set(runner.active_rows())
+        for key in [k for k in self._row_req
+                    if k[0] == group and k[1] not in live]:
+            r = self._row_req.pop(key)
+            runner.padded_positions -= self.bucket_len - r.length
+            self._retired[r.plan] = r
 
     def run(self) -> dict[int, Request]:
         """Pump to completion; returns completed requests by id."""
