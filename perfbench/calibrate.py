@@ -10,9 +10,10 @@
 run does, and prints per seed the program's compared numbers and verdict,
 and the control's on the same requests (the configuration's
 ``check.control`` mode of the reference put in the program's place,
-judged by the same checks).  The largest program gap over the seeds and
-the smallest control gap are the two readings the ``logit_gap_limit``
-lies between.
+judged by the same checks), with both gap numbers of each (``gaps``)
+whether compared or not.  The largest program reading of a number over
+the seeds and the smallest control reading are the two readings its
+limit (``check.<number>_limit``) lies between.
 
 ``sweep`` serves the cell's open-loop traffic at each given rate (and,
 with ``--backlog``, from a full queue first, which gives the rate the
@@ -69,7 +70,7 @@ def main(argv=None) -> int:
             res = run.run_cell(cell, seed, args.seconds, False, peaks,
                                control=True)
             print(json.dumps({"seed": seed, "correct": res["correct"],
-                              "checks": res["checks"],
+                              "checks": res["checks"], "gaps": res["gaps"],
                               "control": res["control"],
                               "metrics": res["metrics"]}), flush=True)
         return 0

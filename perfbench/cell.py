@@ -1,14 +1,17 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` pairs a configuration
 file (``configs/<name>.json``) with a traffic file (``traffic/<name>.json``)
-and lists the metrics the cell reports."""
+and lists the metrics the cell reports; the configuration file names the
+module that builds it (``models/<model>.py``)."""
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import pathlib
 
 HERE = pathlib.Path(__file__).resolve().parent
 CHECKOUT = HERE.parent
+MODELS_DIR = HERE / "models"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,24 +51,23 @@ def load(workload: str, benchmark: pathlib.Path | None = None) -> Cell:
                         if _reports(m, workload)))
 
 
-def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a configuration file: the
-    registry entry it names, with every size taken from the file, run as
-    a bidirectional denoiser the way ``launch/serve.build_engine`` does."""
-    import repro.configs as registry
-    from repro.models.config import dense_pattern
+def model_module(conf: dict):
+    """The module that builds a configuration: ``models/<model>.py``,
+    named by the file's ``model`` key.  It exposes ``model_config(conf)``,
+    the program's ``ModelConfig``, and ``forward_flops(conf, n)``, the
+    FLOPs of one forward pass over an ``n``-token canvas."""
+    path = MODELS_DIR / f"{conf['model']}.py"
+    if not path.is_file():
+        raise ValueError(f"{conf['name']}: no model module {path.name} "
+                         f"under {MODELS_DIR}")
+    spec = importlib.util.spec_from_file_location(
+        f"perfbench_model_{conf['model']}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
-    if conf["hidden_act"] != "silu":
-        raise ValueError(f"{conf['name']}: only SwiGLU (silu) MLPs are run")
-    layers = conf["num_hidden_layers"]
-    window = conf.get("sliding_window") or 0
-    return registry.get(conf["registry"]).replace(
-        n_layers=layers, block_pattern=dense_pattern(layers, window),
-        sliding_window=window,
-        d_model=conf["hidden_size"], d_ff=conf["intermediate_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        vocab_size=conf["vocab_size"], rope_theta=conf["rope_theta"],
-        norm_eps=conf["rms_norm_eps"], mlp_type="swiglu",
-        tie_embeddings=conf["tie_word_embeddings"],
-        dtype=conf["torch_dtype"], bidirectional=True)
+
+def model_config(conf: dict):
+    """The program's ``ModelConfig`` for a configuration file, as its
+    model module builds it."""
+    return model_module(conf).model_config(conf)

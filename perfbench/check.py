@@ -1,26 +1,30 @@
 """The output check that decides ``correct``.
 
-Three numbers are compared, each against its own limit:
+These numbers are compared, each against its own limit:
 
 * ``bad_results`` (limit 0): requests of the run that never completed,
   or came back at another length than asked, with an id outside the
   vocabulary, or with a mask id left;
 * ``window_compiles`` (limit 0): programs compiled or loaded while the
   window (and the drain after it) ran;
-* ``logit_gap`` (limit from the configuration file): the served tokens
-  against the plain reference.  A DNDM request reveals position j once,
-  at the call whose time equals its transition time tau_j, and never
-  changes it again, so the canvas each call saw is known from the served
-  tokens alone: ``where(tau > t, served, mask)``.  The reference runs over
-  those canvases, and for each position revealed inside the request's
-  length it adds the same Gumbel noise the program drew from that call's
-  key.  The gap is how far the served token's perturbed reference logit
-  lies below the best one; the widest gap over the sampled requests is
-  compared.  The reference computes at the precision the configuration
-  states (``check.precision``, a mode of the reference module).  The
-  control reads, at the same canvases and noise, the gap of the token
-  that the reference at the next precision down (``check.control``) puts
-  first.
+* ``logit_gap`` and ``mean_logit_gap``, each where the configuration file
+  states its limit (``check.<name>_limit``): the served tokens against
+  the plain reference.  A DNDM request reveals position j once, at the
+  call whose time equals its transition time tau_j, and never changes it
+  again, so the canvas each call saw is known from the served tokens
+  alone: ``where(tau > t, served, mask)``.  The reference runs over those
+  canvases, and for each position revealed inside the request's length it
+  adds the same Gumbel noise the program drew from that call's key.  The
+  gap is how far the served token's perturbed reference logit lies below
+  the best one; ``logit_gap`` is the widest gap over the sampled requests
+  and ``mean_logit_gap`` the mean over every position checked (0 where
+  the served token is the reference's pick).  The widest gap swings from
+  seed to seed by its nature; the mean is steadier, and separates a
+  control whose rounding noise is only a few times the program's.  The
+  reference computes at the precision the configuration states
+  (``check.precision``, a mode of the reference module).  The control
+  reads, at the same canvases and noise, the gap of the token that the
+  reference at the next precision down (``check.control``) puts first.
 """
 from __future__ import annotations
 
@@ -71,15 +75,18 @@ def _pad(a: np.ndarray, n: int) -> np.ndarray:
 
 @jax.jit
 def _gaps(logits_ref, logits_ctrl, g, served, valid, pen):
+    """(widest, summed) gap of the served tokens over the valid positions,
+    and of the control's picks (``None`` without a control)."""
     a = logits_ref + pen + g
     best = a.max(-1)
     got = jnp.take_along_axis(a, served[:, None], -1)[:, 0]
-    gap = jnp.where(valid, best - got, 0.0).max()
+    gap = jnp.where(valid, best - got, 0.0)
     if logits_ctrl is None:
-        return gap, None
+        return (gap.max(), gap.sum()), None
     pick = jnp.argmax(logits_ctrl + pen + g, -1)
     alt = jnp.take_along_axis(a, pick[:, None], -1)[:, 0]
-    return gap, jnp.where(valid, best - alt, 0.0).max()
+    ctrl = jnp.where(valid, best - alt, 0.0)
+    return (gap.max(), gap.sum()), (ctrl.max(), ctrl.sum())
 
 
 @jax.jit
@@ -95,15 +102,16 @@ def _noise_rows(keys, sel_c, sel_j, vocab_row):
 def replay_gap(params, conf: dict, reqs: list[Served], *, steps: int,
                block: int, precision: str,
                control: str | None = None) -> dict:
-    """Widest gap of the served tokens (and of the control's picks, when
-    ``control`` names a reference mode) over ``reqs``, against the
-    reference in mode ``precision``."""
+    """The gaps of the served tokens over ``reqs`` against the reference
+    in mode ``precision``: the widest (``logit_gap``) and the mean over
+    every checked position (``mean_logit_gap``); and the same of the
+    control's picks under ``"control"`` when ``control`` names a
+    reference mode."""
     ref = reference(conf)
     vocab, mask_id = conf["vocab_size"], conf["mask_id"]
     pen = jnp.zeros((vocab,), jnp.float32).at[mask_id].set(MASK_PENALTY)
     vrow = jnp.zeros((vocab,), jnp.float32)
-    gap = ctrl_gap = 0.0
-    tokens = 0
+    acc = [0, 0.0, 0.0, 0.0, 0.0]   # tokens, widest, sum (and control)
     for r in reqs:
         n = len(r.canvas)
         nfe = len(r.times)
@@ -118,7 +126,7 @@ def replay_gap(params, conf: dict, reqs: list[Served], *, steps: int,
                            & (np.arange(n) < r.length))[0]
             if not len(j):
                 continue
-            tokens += len(j)
+            acc[0] += len(j)
             sel_c = np.zeros(n, np.int32)
             sel_j = np.zeros(n, np.int32)
             valid = np.zeros(n, bool)
@@ -140,10 +148,16 @@ def replay_gap(params, conf: dict, reqs: list[Served], *, steps: int,
                 lc = ref.logits(params, hc[sel_c, sel_j], mode=control)
             a, b = _gaps(lg, lc, g, jnp.asarray(served), jnp.asarray(valid),
                          pen)
-            gap = max(gap, float(a))
-            if b is not None:
-                ctrl_gap = max(ctrl_gap, float(b))
-    out = {"logit_gap": gap, "tokens": tokens}
+            for k, got in ((1, a), (3, b)):
+                if got is not None:
+                    acc[k] = max(acc[k], float(got[0]))
+                    acc[k + 1] += float(got[1])
+    tokens = acc[0]
+
+    def stats(k):
+        return {"logit_gap": acc[k],
+                "mean_logit_gap": acc[k + 1] / tokens if tokens else 0.0}
+    out = dict(stats(1), tokens=tokens)
     if control:
-        out["control_gap"] = ctrl_gap
+        out["control"] = stats(3)
     return out

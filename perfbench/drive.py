@@ -52,15 +52,16 @@ class LoadGen:
         self.in_flight = 0                      # submitted, not done
         self.t_open = self.t_close = 0.0
         self.trace_hook = None                  # called once per loop turn
-        self.live_rows: list[int] = []          # live rows of each step
+        self.live_rows: list[int] = []          # live rows of each call
         self._finished: list[np.ndarray] = []   # whole rows, this pump
         self._wrap_runners(engine)
 
     def _wrap_runners(self, engine) -> None:
-        """Count the live rows of every step in the order they are
-        dispatched, and keep the whole canvas of each finished row: the
-        scheduler hands back each request's first ``length`` tokens, and
-        the check needs every position the denoiser saw."""
+        """Count the live rows of every call in the order the calls are
+        dispatched (a ``step()`` with no live row dispatches none), and
+        keep the whole canvas of each finished row: the scheduler hands
+        back each request's first ``length`` tokens, and the check needs
+        every position the denoiser saw."""
         make = engine.stepwise
 
         def stepwise(*a, **k):
@@ -68,7 +69,9 @@ class LoadGen:
             step = runner.step
 
             def kept_step():
-                self.live_rows.append(len(runner.active_rows()))
+                rows = len(runner.active_rows())
+                if rows:
+                    self.live_rows.append(rows)
                 done = step()
                 self._finished.extend(done.values())
                 return done

@@ -6,30 +6,15 @@ work, and nothing recomputed counts twice.
 """
 from __future__ import annotations
 
+from perfbench import cell
+
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2}
 
 
 def denoiser_flops(conf: dict, n: int) -> float:
     """FLOPs of one forward pass of the denoiser over one canvas of ``n``
-    tokens (one row of one batched network call): per layer the q/k/v/o
-    projections, bidirectional attention scores and values over all
-    ``n`` keys, and the SwiGLU MLP; then the LM head and the time-embedding
-    MLP.  Norms, RoPE and softmax are elementwise and left out."""
-    d = conf["hidden_size"]
-    f = conf["intermediate_size"]
-    h = conf["num_attention_heads"]
-    kv = conf["num_key_value_heads"]
-    hd = d // h
-    v = conf["vocab_size"]
-    window = conf.get("sliding_window") or n
-    keys = min(window, n)
-    proj = 2 * n * d * (h * hd + 2 * kv * hd) + 2 * n * h * hd * d
-    attn = 2 * 2 * n * keys * h * hd
-    mlp = 3 * 2 * n * d * f
-    layer = proj + attn + mlp
-    head = 2 * n * d * v
-    time_mlp = 2 * 2 * d * d
-    return float(conf["num_hidden_layers"] * layer + head + time_mlp)
+    tokens, as the configuration's model module counts them."""
+    return float(cell.model_module(conf).forward_flops(conf, n))
 
 
 def decode_scores_work(batch: int, n: int, vocab: int, logits_dtype: str,

@@ -216,16 +216,16 @@ def measure(cell, seed: int, device_peaks: dict, record_path=None,
     kept: dict = {}
 
     class KeepingTracer(run.Tracer):
-        """``run.Tracer`` that reads the program's events as well, before
-        its ``read`` deletes the trace."""
+        """``run.Tracer`` that keeps the raw events as well, before its
+        ``read`` deletes the trace."""
 
         def read(self):
             kept["prog_raw"] = progtrace.raw_events(self.dir)
             kept["dev_raw"] = devtrace.raw_events(self.dir)
             if record_path or save_path:
                 kept["events"] = all_events(self.dir)
-            kept["trace"] = super().read()
-            return kept["trace"]
+            kept["trace"], program = super().read()
+            return kept["trace"], program
 
     run.Tracer = KeepingTracer
     result = run.run_cell(cell, seed, run.TRACE_S, True, device_peaks,

@@ -8,9 +8,9 @@ Builds the cell's denoiser with weights made from ``--seed`` on the
 device, warms the programs the cell's traffic uses, brings the rolling
 batch to a steady state, and then measures ``--seconds`` of serving
 through ``ContinuousScheduler``.  ``--trace 0`` reports the cell's
-end-to-end metrics; ``--trace 1`` profiles the first seconds of the
-window and reports its per-layer metrics.  After the window the served
-tokens are checked against the plain reference (``check.py``).
+end-to-end metrics; ``--trace 1`` serves the same window, profiles its
+first seconds and reports their per-layer metrics.  After the window the
+served tokens are checked against the plain reference (``check.py``).
 
 The last line of standard output is one JSON object; the last lines of
 standard error give each compared number beside its limit.  With no TPU,
@@ -41,13 +41,17 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from perfbench import cell as cell_lib  # noqa: E402
-from perfbench import check, devtrace, flops, peaks  # noqa: E402
+from perfbench import check, devtrace, flops, peaks, progtrace  # noqa: E402
 from perfbench import tape as tape_lib  # noqa: E402
 from perfbench import weights  # noqa: E402
 from perfbench.drive import LoadGen  # noqa: E402
 
 METRICS_DIR = pathlib.Path(__file__).resolve().parent / "metrics"
-TRACE_S = 4.0       # a --trace 1 run measures only this traced window
+TRACE_S = 4.0       # a --trace 1 run's per-layer metrics read only the
+                    # first seconds of its window, which it profiles
+GAPS = ("logit_gap", "mean_logit_gap")      # check.py's numbers of the
+                                            # served tokens, each compared
+                                            # where its limit is stated
 _COMPILES: list[float] = []     # perf_counter stamps of program lowerings
 
 
@@ -80,24 +84,15 @@ def nfe_law(steps: int, n: int) -> tuple[float, float]:
     return mean, math.sqrt(max(var, 0.0))
 
 
-def nfe_range(steps: int, n: int, sds: float = 7.0) -> range:
-    """Call counts a request can have, to the far tails (mean +- ``sds``
-    standard deviations)."""
-    mean, sd = nfe_law(steps, n)
-    return range(max(1, math.floor(mean - sds * sd)),
-                 min(steps, n, math.ceil(mean + sds * sd)) + 1)
-
-
 def warm(engine, traffic: dict) -> None:
     """Compile (or load) every program the cell's traffic runs: the plan
-    path at (1, canvas), the key split for every call count a request can
-    have, the admission scatter for 1..max_batch rows at once, and the
-    batched step at (max_batch, canvas)."""
+    path at (1, canvas) on the host's CPU device (one fixed-length key
+    split whatever a request's call count), the admission scatter for
+    1..max_batch rows at once, and the batched step at (max_batch,
+    canvas)."""
     n, rows, method = traffic["canvas"], traffic["max_batch"], \
         traffic["method"]
     plan = engine.plan_request(jax.random.PRNGKey(0), n, method)
-    for k in nfe_range(traffic["steps"], n):
-        jax.random.split(jax.random.PRNGKey(0), k).block_until_ready()
     for k in range(1, rows + 1):
         runner = engine.stepwise(rows, n, method)
         runner.admit_many([(row, plan) for row in range(k)])
@@ -108,12 +103,14 @@ def warm(engine, traffic: dict) -> None:
 class Tracer:
     """Profiles the run into a directory under ``TMPDIR``, read and
     deleted after the run.  The profiler starts during set-up, since
-    starting it takes seconds, and stops after the drive, since stopping
-    it stalls the host while it writes the trace; the ``bench.window``
-    span marks the window the metrics read."""
+    starting it takes seconds, and stops when the first ``seconds`` of
+    the window have passed (stopping it stalls the host while it writes
+    the trace; the run serves on untraced); the ``bench.window`` span
+    marks the seconds the metrics read."""
 
-    def __init__(self, load: LoadGen):
+    def __init__(self, load: LoadGen, seconds: float):
         self.load = load
+        self.seconds = seconds
         self.dir = tempfile.mkdtemp(prefix="perfbench-trace-")
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0        # it slows the host twofold
@@ -126,9 +123,12 @@ class Tracer:
         if self.state == "idle" and now >= d.t_open:
             self.window.__enter__()
             self.state = "on"
-        elif self.state == "on" and now >= d.t_close:
-            self.window.__exit__(None, None, None)
-            self.state = "closed"
+        elif self.state == "on" and now >= self.t_close:
+            self.stop()
+
+    @property
+    def t_close(self) -> float:
+        return min(self.load.t_open + self.seconds, self.load.t_close)
 
     def stop(self) -> None:
         if self.state == "on":
@@ -137,9 +137,18 @@ class Tracer:
             jax.profiler.stop_trace()
             self.state = "done"
 
-    def read(self) -> devtrace.Trace:
+    def read(self) -> tuple[devtrace.Trace, progtrace.ProgramTrace]:
+        """The harness's reduction of the trace and the program's own
+        spans and scopes in it; the trace is deleted after."""
         try:
-            return devtrace.from_xplane(self.dir)
+            t0 = time.perf_counter()
+            trace = devtrace.from_xplane(self.dir)
+            t1 = time.perf_counter()
+            program = progtrace.from_xplane(self.dir)
+            say(f"trace read: devtrace {t1 - t0:.3f} s, program events "
+                f"{time.perf_counter() - t1:.3f} s ({len(program.spans)} "
+                f"spans, {len(program.scoped)} scoped operations)")
+            return trace, program
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -174,17 +183,18 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
     checks, as ``result["control"]``."""
     from repro.serving import ContinuousScheduler
     conf, traffic = cell.config, cell.traffic
+    if not any(f"{name}_limit" in conf["check"] for name in GAPS):
+        raise ValueError(f"{conf['name']}: the check states a limit for "
+                         f"none of {GAPS}")
     t_start = time.perf_counter() if t_start is None else t_start
     params, engine = build(conf, traffic, seed)
     warm(engine, traffic)
-    if trace:
-        seconds = TRACE_S
     tape = tape_lib.make(traffic, seed, seconds)
     sched = ContinuousScheduler(engine, max_batch=traffic["max_batch"],
                                 bucket_len=traffic["canvas"], seed=seed)
     load = LoadGen(sched, engine, tape, max_batch=traffic["max_batch"],
                    nfe_mean=nfe_law(traffic["steps"], traffic["canvas"])[0])
-    tracer = Tracer(load) if trace else None
+    tracer = Tracer(load, TRACE_S) if trace else None
     load.trace_hook = tracer
     if tape.open_loop:
         load.run_open_loop(seconds)
@@ -223,18 +233,26 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
         f"{load.next} submitted in the run, setup {setup_s:.3f} s, "
         f"run {t_end - t_start:.3f} s, peak_bytes_in_use {mem_peak}")
 
+    # the readers of a traced run see its traced seconds; the check below
+    # samples the whole window's completions either way
+    seen, seen_done, seen_sched = window_s, done, attempted
+    if trace:
+        t_seen = tracer.t_close
+        seen = t_seen - load.t_open
+        seen_done = [r for r in done if r.done < t_seen]
+        seen_sched = [r for r in attempted if r.scheduled < t_seen]
     ctx = types.SimpleNamespace(
         cell=cell, conf=conf, traffic=traffic, peaks=device_peaks,
-        flops=flops, devtrace=devtrace, window_s=window_s, completed=done,
-        scheduled=attempted, latencies=lat, nearest_rank=nearest_rank,
+        flops=flops, devtrace=devtrace, window_s=seen, completed=seen_done,
+        scheduled=seen_sched, latencies=lat, nearest_rank=nearest_rank,
         clock_offset=time.time() - time.perf_counter(),
-        live_rows=load.live_rows, trace=None)
+        live_rows=load.live_rows, trace=None, program=None)
     result: dict = {"correct": False, "attempted": len(attempted),
                     "failed": int(failed), "metrics": {}}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": mem_peak}
     if trace:
-        tr = tracer.read()
+        tr, ctx.program = tracer.read()
         ctx.trace = tr
         device["busy_s"] = devtrace.busy_s(tr)
         device["window_s"] = tr.window_s
@@ -262,7 +280,8 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     t0 = time.perf_counter()
     if any(s is None for s in served) or not served:
-        gap = {"logit_gap": 1e9, "control_gap": 1e9, "tokens": 0}
+        none = {name: 1e9 for name in GAPS}
+        gap = dict(none, control=none, tokens=0)
     else:
         gap = check.replay_gap(
             params, conf, served, steps=traffic["steps"],
@@ -271,20 +290,23 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
     say(f"reference: {len(served)} requests, {gap['tokens']} tokens "
         f"checked in {time.perf_counter() - t0:.3f} s")
 
-    def judged(logit_gap: float) -> tuple[bool, dict]:
+    def judged(gaps: dict) -> tuple[bool, dict]:
         checks = {
             "bad_results": {"value": int(bad), "limit": 0},
             "window_compiles": {"value": int(compiles), "limit": 0},
-            "logit_gap": {"value": logit_gap,
-                          "limit": spec["logit_gap_limit"]},
         }
+        for name in GAPS:
+            if f"{name}_limit" in spec:
+                checks[name] = {"value": gaps[name],
+                                "limit": spec[f"{name}_limit"]}
         return all(c["value"] <= c["limit"] for c in checks.values()), checks
 
     if control:
-        ok, checks = judged(gap["control_gap"])
+        ok, checks = judged(gap["control"])
         result["control"] = {"mode": spec["control"], "correct": ok,
-                             "checks": checks}
-    result["correct"], result["checks"] = judged(gap["logit_gap"])
+                             "checks": checks, "gaps": gap["control"]}
+        result["gaps"] = {name: gap[name] for name in GAPS}
+    result["correct"], result["checks"] = judged(gap)
     for name, c in result["checks"].items():
         say(f"check {name} {c['value']} limit {c['limit']}")
     return result

@@ -23,12 +23,14 @@ def tiny_cell(arrivals="poisson") -> cell.Cell:
     conf.update(num_hidden_layers=2, hidden_size=64, intermediate_size=128,
                 num_attention_heads=4, num_key_value_heads=4)
     # on the CPU the program's float32 matmuls are exact float32; at this
-    # size its gap reads 0.0 and the bfloat16 control's 0.0017 and up
-    # (test_perfbench_control.py), so the limit lies between them
+    # size both its gaps read 0.0, and the bfloat16 control's widest gap
+    # 0.0157 and up and its mean 3.9e-5 and up (test_perfbench_control.py),
+    # so each limit lies between them
     conf["check"] = dict(conf["check"], requests=3, block=8,
-                         precision="highest", logit_gap_limit=1e-3)
+                         precision="highest", logit_gap_limit=1e-3,
+                         mean_logit_gap_limit=1e-5)
     traffic = cell.load_json(cell.HERE / "traffic"
-                             / "poisson-t50-b8-r20.json")
+                             / "poisson-t50-b8-r27.json")
     traffic.update(arrivals=arrivals, canvas=16, length_min=8,
                    length_max=16, steps=8, max_batch=4, lead_in_s=0.3,
                    tail_s=5.0, rate_per_s=40.0)
@@ -86,5 +88,15 @@ def test_fault_turns_correct_false(monkeypatch, fault):
     res = _run(dataclasses.replace(tiny_cell("backlog")))
     assert not res["correct"], res["checks"]
     failing = [k for k, c in res["checks"].items() if c["value"] > c["limit"]]
-    want = "logit_gap" if fault == "token_altered" else "bad_results"
-    assert want in failing, res["checks"]
+    want = ({"logit_gap", "mean_logit_gap"} if fault == "token_altered"
+            else {"bad_results"})
+    assert want <= set(failing), res["checks"]
+
+
+def test_a_check_without_a_gap_limit_is_refused():
+    c = tiny_cell("backlog")
+    spec = {k: v for k, v in c.config["check"].items()
+            if not k.endswith("gap_limit")}
+    c = dataclasses.replace(c, config=dict(c.config, check=spec))
+    with pytest.raises(ValueError, match="limit"):
+        _run(c)

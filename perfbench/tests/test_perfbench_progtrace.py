@@ -210,3 +210,80 @@ def test_recorded_tpu_program_trace():
         assert (got is None) == (want is None), name
         if want is not None:
             assert got == pytest.approx(want), name
+
+
+def test_traced_run_hands_readers_program_events(monkeypatch):
+    """A traced run (``run_cell`` with ``trace``) reads the program's spans
+    from the trace before deleting it and passes them to every reader as
+    ``ctx.program``: on the CPU at a tiny size the serving spans are
+    there, and ``padded_position_share`` reads a value from them."""
+    import dataclasses
+
+    from perfbench import peaks
+    from perfbench.tests.test_perfbench_faults import tiny_cell
+    seen = []
+    read_metric = run.read_metric
+
+    def reading(name, ctx):
+        seen.append((name, ctx.program))
+        return read_metric(name, ctx)
+    monkeypatch.setattr(run, "read_metric", reading)
+    monkeypatch.setattr(run, "TRACE_S", 0.6)
+    c = dataclasses.replace(tiny_cell("backlog"), per_layer=(
+        {"name": "padded_position_share", "unit": "%"},))
+    res = run.run_cell(c, 2**31 + 11, 0.6, True,
+                       peaks.for_kind("TPU v5 lite"))
+    assert res["correct"], res["checks"]
+    (name, prog), = seen
+    assert name == "padded_position_share"
+    names = {n for n, _, _, _ in prog.spans}
+    assert {"scheduler.pump", "engine.stepwise"} <= names
+    share = res["metrics"]["padded_position_share"]["value"]
+    assert 0 < share < 100
+
+
+def test_traced_run_checks_the_whole_window(monkeypatch):
+    """A traced run serves its whole window and profiles only its first
+    ``TRACE_S``: the readers see the traced seconds and their completions,
+    and the check samples from every completion of the window, as an
+    untraced run's does.  The window closes at the fifth completion after
+    the profiler has stopped, however long its stop stalls the host."""
+    import dataclasses
+    import time
+
+    from perfbench import drive, peaks
+    from perfbench.tests.test_perfbench_faults import tiny_cell
+    seen, pools, mark = [], [], []
+    read_metric, sample = run.read_metric, run.check.sample
+    pump = drive.LoadGen.pump
+
+    def counted(self):
+        pump(self)
+        if getattr(self.trace_hook, "state", None) != "done":
+            return
+        n = len(self.completed_in_window())
+        mark[:] = mark or [n]
+        if n >= mark[0] + 5:
+            self.t_close = min(self.t_close, time.perf_counter() + 1e-9)
+
+    def reading(name, ctx):
+        seen.append(ctx)
+        return read_metric(name, ctx)
+
+    def sampling(done, k, seed):
+        pools.append(list(done))
+        return sample(done, k, seed)
+    monkeypatch.setattr(run, "read_metric", reading)
+    monkeypatch.setattr(run.check, "sample", sampling)
+    monkeypatch.setattr(drive.LoadGen, "pump", counted)
+    monkeypatch.setattr(run, "TRACE_S", 0.3)
+    c = dataclasses.replace(tiny_cell("backlog"), per_layer=(
+        {"name": "nfe_per_request", "unit": "calls"},))
+    res = run.run_cell(c, 2**31 + 12, 120.0, True,
+                       peaks.for_kind("TPU v5 lite"))
+    assert res["correct"], res["checks"]
+    (ctx,), (pool,) = seen, pools
+    assert ctx.window_s == pytest.approx(0.3)
+    assert 0 < len(ctx.completed) < len(pool) == res["attempted"]
+    assert {id(r) for r in ctx.completed} <= {id(r) for r in pool}
+    assert res["metrics"]["nfe_per_request"]["value"] > 0

@@ -7,7 +7,7 @@ import numpy as np
 from perfbench import cell, tape
 
 POISSON = cell.load_json(cell.HERE / "traffic"
-                         / "poisson-t50-b8-r20.json")
+                         / "poisson-t50-b8-r27.json")
 BACKLOG = cell.load_json(cell.HERE / "traffic" / "backlog-t50-b8.json")
 BIG_SEED = 2**31 + 987_654_321
 

@@ -129,3 +129,32 @@ def test_step_mfu_pairs_executions_with_dispatches():
         100 * work / (0.1 * 197e12))
     ctx.live_rows = ctx.live_rows[:4]
     assert run.read_metric("step_mfu", ctx) is None
+
+
+def test_live_rows_count_dispatched_calls_only():
+    """``step_mfu`` pairs the trace's executions with the live-row counts
+    in order, so a ``step()`` that dispatches no call (a drain: no live
+    row) must add no count."""
+    from perfbench import drive
+
+    class Runner:
+        def __init__(self):
+            self.live = [2, 1, 0, 3]
+
+        def active_rows(self):
+            return list(range(self.live[0]))
+
+        def step(self):
+            self.live.pop(0)
+            return {}
+
+    class Engine:
+        def stepwise(self, *a, **k):
+            return Runner()
+
+    engine = Engine()
+    load = drive.LoadGen(None, engine, None, max_batch=4)
+    runner = engine.stepwise(4, 16)
+    for _ in range(4):
+        runner.step()
+    assert load.live_rows == [2, 1, 3]
