@@ -24,6 +24,7 @@ _ARCH_MODULES = {
     "tinyllama-1.1b": "tinyllama_1p1b",
     "dndm-text8": "dndm_text8",
     "dndm-mt": "dndm_mt",
+    "sdar-30b-a3b": "sdar_30b_a3b",
 }
 
 ASSIGNED_ARCHS = tuple(list(_ARCH_MODULES)[:10])

@@ -8,7 +8,10 @@ Three interchangeable inner implementations (``cfg.attn_impl``):
   pallas  — kernels/flash_attention (compiled on TPU, interpreted elsewhere)
 
 Mask semantics: ``causal`` plus optional ``sliding_window`` (only the last
-W positions visible).  The diffusion denoiser runs with causal=False.
+W positions visible), and ``cfg.block_causal`` (SDAR's block diffusion:
+bidirectional inside a block of b positions, causal across blocks).  The
+diffusion denoiser runs with causal=False.  ``cfg.qk_norm`` applies a
+per-head RMSNorm to q and k (Qwen3) before RoPE.
 """
 from __future__ import annotations
 
@@ -16,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import apply_rope, dense_init, rope_freqs
+from repro.models.layers import (apply_rope, dense_init, rmsnorm,
+                                 rmsnorm_init, rope_freqs)
 
 Array = jnp.ndarray
 NEG = -1e9
@@ -26,13 +30,17 @@ def init(key, cfg: ModelConfig) -> dict:
     hd = cfg.hd
     ks = jax.random.split(key, 4)
     dt = jnp.dtype(cfg.dtype)
-    return {
+    p = {
         "wq": dense_init(ks[0], cfg.d_model, cfg.n_heads * hd, dt),
         "wk": dense_init(ks[1], cfg.d_model, cfg.n_kv_heads * hd, dt),
         "wv": dense_init(ks[2], cfg.d_model, cfg.n_kv_heads * hd, dt),
         "wo": dense_init(ks[3], cfg.n_heads * hd, cfg.d_model, dt,
                          scale=1.0 / max(cfg.n_layers, 1) ** 0.5),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = rmsnorm_init(hd, dt)
+        p["k_norm"] = rmsnorm_init(hd, dt)
+    return p
 
 
 def _qkv(params, x, cfg: ModelConfig, positions):
@@ -41,6 +49,9 @@ def _qkv(params, x, cfg: ModelConfig, positions):
     q = (x @ params["wq"]).reshape(B, S, cfg.n_heads, hd)
     k = (x @ params["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
     v = (x @ params["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
+    if cfg.qk_norm:
+        q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
+        k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
     cos, sin = rope_freqs(hd, cfg.rope_theta, positions)
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
@@ -53,14 +64,17 @@ def _repeat_kv(k: Array, n_heads: int) -> Array:
 
 
 def _mask_bias(q_pos: Array, k_pos: Array, causal: bool,
-               window: int) -> Array:
-    """(..., Sq, Sk) additive bias from position grids."""
+               window: int, block: int = 0) -> Array:
+    """(..., Sq, Sk) additive bias from position grids; ``block`` > 0
+    lets q see k only where k's block is not after q's."""
     diff = q_pos[..., :, None] - k_pos[..., None, :]
     ok = jnp.ones(diff.shape, bool)
     if causal:
         ok &= diff >= 0
     if window > 0:
         ok &= jnp.abs(diff) < window if not causal else diff < window
+    if block > 0:
+        ok &= k_pos[..., None, :] // block <= q_pos[..., :, None] // block
     return jnp.where(ok, 0.0, NEG)
 
 
@@ -141,7 +155,8 @@ def apply(params: dict, x: Array, cfg: ModelConfig, *, causal: bool,
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     q, k, v = _qkv(params, x, cfg, positions)
-    bias = _mask_bias(jnp.arange(S), jnp.arange(S), causal, window)
+    bias = _mask_bias(jnp.arange(S), jnp.arange(S), causal, window,
+                      cfg.block_causal)
     bias = jnp.broadcast_to(bias, (B, S, S))
     y = _inner(q, k, v, bias, cfg)
     return y.reshape(B, S, cfg.n_heads * cfg.hd) @ params["wo"]

@@ -37,7 +37,7 @@ def _attn_init(key, cfg: ModelConfig, is_moe: bool) -> dict:
 
 
 def _attn_apply(params, x, cfg: ModelConfig, *, causal: bool, window: int,
-                is_moe: bool):
+                is_moe: bool, experts=None):
     # named scopes: each sub-layer, its pre-norm and residual add
     with jax.named_scope("attention"):
         h = rmsnorm(params["ln1"], x, cfg.norm_eps)
@@ -47,7 +47,7 @@ def _attn_apply(params, x, cfg: ModelConfig, *, causal: bool, window: int,
     with jax.named_scope("mlp"):
         h = rmsnorm(params["ln2"], x, cfg.norm_eps)
         if is_moe:
-            y, aux = moe.apply(params["moe"], h, cfg)
+            y, aux = moe.apply(params["moe"], h, cfg, experts)
             x = x + y
         elif "mlp" in params:
             x = x + mlp(params["mlp"], h, cfg.mlp_type)
@@ -89,14 +89,16 @@ def init(kind: str, key, cfg: ModelConfig) -> dict:
 
 
 def apply(kind: str, params: dict, x: Array, cfg: ModelConfig, *,
-          causal: bool) -> tuple[Array, dict]:
+          causal: bool, experts=None) -> tuple[Array, dict]:
+    """One block.  ``experts``: a "moe" block's expert weights stacked
+    over the layer scan and the layer's index (``moe.apply``)."""
     bidir = not causal
     # "swa" blocks always window; "moe" blocks window when configured
     # (Mixtral: SWA + MoE in the same layer)
     window = cfg.sliding_window if kind in ("swa", "moe") else 0
     if kind in ("attn", "swa", "shared_attn", "moe"):
         return _attn_apply(params, x, cfg, causal=causal, window=window,
-                           is_moe=(kind == "moe"))
+                           is_moe=(kind == "moe"), experts=experts)
     h = rmsnorm(params["ln"], x, cfg.norm_eps)
     if kind == "mamba2":
         y = mamba2.apply(params["mixer"], h, cfg, bidirectional=bidir)

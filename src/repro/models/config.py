@@ -45,13 +45,17 @@ class ModelConfig:
     attn_impl: str = "einsum"            # einsum | blocked | pallas
     attn_block_q: int = 512              # blocked/pallas tile sizes
     attn_block_k: int = 512
+    qk_norm: bool = False                # per-head RMSNorm on q and k
+    block_causal: int = 0                # >0: q sees k iff k//b <= q//b
 
     # mlp
     mlp_type: str = "swiglu"             # swiglu | gelu
 
     # moe
-    n_experts: int = 0
+    n_experts: int = 0                   # the router's width: every expert
     experts_per_token: int = 0
+    held_experts: int = 0                # experts this chip holds, 0 = all
+    expert_offset: int = 0               # id of the first held expert
     capacity_factor: float = 1.25
     router_z_weight: float = 1e-3
     load_balance_weight: float = 1e-2
@@ -93,6 +97,10 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts or self.n_experts
 
     @property
     def d_inner(self) -> int:

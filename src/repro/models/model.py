@@ -12,7 +12,9 @@ periodicity).  Non-shared block weights are stacked along a leading
 ``n_super`` axis and the stack runs as one ``lax.scan`` (fast compiles) or
 fully unrolled (``scan_layers=False`` — accurate dry-run cost analysis).
 ``shared_attn`` blocks hold a single weight set used by every occurrence
-(Zamba-style), while each occurrence gets its own cache slot.
+(Zamba-style), while each occurrence gets its own cache slot.  A ``moe``
+block is also handed its expert weights' whole stack and the layer's
+index, so its grouped-matmul kernel reads them in place (``moe.apply``).
 """
 from __future__ import annotations
 
@@ -79,13 +81,20 @@ class Model:
                 h = h + time_embed(params["time"], t, cfg.d_model)[:, None]
         h = frontend.fuse(h, frontend_embeds)
 
-        def superblock(h, unit_slice):
+        has_moe = "moe" in self.unit
+
+        def superblock(h, unit_slice, layer=None):
             aux_tot = jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
             lb, rz = aux_tot
             for i, kind in enumerate(self.unit):
                 p = (params["shared"] if kind == "shared_attn"
                      else unit_slice[f"b{i}"])
-                h, aux = blocks.apply(kind, p, h, cfg, causal=causal)
+                experts = None
+                if kind == "moe":        # the gradient goes to the slice
+                    experts = (jax.lax.stop_gradient(
+                        params["unit"][f"b{i}"]["moe"]), layer)
+                h, aux = blocks.apply(kind, p, h, cfg, causal=causal,
+                                      experts=experts)
                 if aux:
                     lb = lb + aux["load_balance"]
                     rz = rz + aux["router_z"]
@@ -96,13 +105,16 @@ class Model:
             body = jax.checkpoint(superblock)
 
         if cfg.scan_layers:
-            h, (lbs, rzs) = jax.lax.scan(body, h, params["unit"])
+            xs = (params["unit"],)
+            if has_moe:                  # and each layer's index
+                xs += (jnp.arange(self.n_super, dtype=jnp.int32),)
+            h, (lbs, rzs) = jax.lax.scan(lambda h, xs: body(h, *xs), h, xs)
             lb, rz = lbs.sum(), rzs.sum()
         else:
             lb = rz = jnp.zeros((), jnp.float32)
             for j in range(self.n_super):
                 sl = jax.tree.map(lambda x: x[j], params["unit"])
-                h, (lb_j, rz_j) = body(h, sl)
+                h, (lb_j, rz_j) = body(h, sl, j if has_moe else None)
                 lb, rz = lb + lb_j, rz + rz_j
 
         with jax.named_scope("lm_head"):

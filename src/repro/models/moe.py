@@ -1,15 +1,36 @@
-"""Mixture-of-Experts MLP with sort-based capacity dispatch.
+"""Mixture-of-Experts MLP: dropless on one device, capacity-routed in
+the dry-run's sharded dispatches.
 
-Top-k routing (Mixtral: 8e top-2; Llama-4-Maverick: 128e top-1).  Tokens
-are dispatched to per-expert buffers of capacity
+Top-k routing (Mixtral: 8e top-2; Llama-4-Maverick: 128e top-1; SDAR /
+Qwen3-MoE: 128e top-8).  The router is a float32 softmax over all
+``n_experts``; the k gates are renormalised to sum to one.
+
+An expert layer may hold only a share of the experts (``held_experts``
+of them, from ``expert_offset``): one chip's part of an expert-parallel
+deployment.  The router keeps its full width, and the layer returns the
+part of the result its own experts give, the chip's partial sum; what
+the absent experts add is left out.
+
+The single-device path (``_dispatch_ffn``, which serving and training
+use) drops nothing: the token-expert pairs routed to a held expert are
+sorted by expert and run through grouped matmuls (``kernels/moe_gmm``:
+the SwiGLU's gate and up in one, then down) whose work follows the
+pairs, so a token's output never depends on the other tokens of its
+batch.  Given the layer's place in the stacked weights of a layer scan
+(``experts``), the kernel reads the weights there rather than from a
+per-layer copy.  Named scopes
+``moe_route`` (router, top-k, sort, gathers, combine) and
+``moe_experts`` (the grouped matmuls) split it inside the block's
+``mlp`` scope.
+
+The dry-run's ``local`` and ``shard_map`` dispatches still route into
+per-expert buffers of capacity
 ``C = ceil(top_k * tokens / E * capacity_factor)`` via an argsort on
-expert id (TPU-friendly: two sorts + gathers, no (T, E, C) one-hot).
-Overflowing tokens are dropped (their expert contribution is zero — the
-residual path still carries them), matching standard capacity routing.
-
-Expert FFNs run as a single batched einsum over stacked weights
-(E, d, ff): with expert-parallel sharding on the model axis this is the
-all-to-all pattern the roofline's collective term tracks.
+expert id and drop the pairs past it (their expert contribution is zero;
+the residual path still carries them).  Their expert FFNs run as a
+single batched einsum over stacked weights (E, d, ff): with
+expert-parallel sharding on the model axis this is the all-to-all
+pattern the roofline's collective term tracks.
 
 Auxiliary losses: router z-loss and load-balance loss (returned, weighted
 by the trainer).
@@ -19,6 +40,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.moe_gmm import gmm
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init
 
@@ -26,12 +48,13 @@ Array = jnp.ndarray
 
 
 def init(key, cfg: ModelConfig) -> dict:
-    E, d, ff = cfg.n_experts, cfg.d_model, cfg.d_ff
+    """The router over all ``n_experts`` and the held experts' weights."""
+    E, d, ff = cfg.n_held, cfg.d_model, cfg.d_ff
     ks = jax.random.split(key, 4)
     dt = jnp.dtype(cfg.dtype)
     std = 1.0 / d ** 0.5
     p = {
-        "router": dense_init(ks[0], d, E, dt, scale=0.1),
+        "router": dense_init(ks[0], d, cfg.n_experts, dt, scale=0.1),
         "down": (jax.random.truncated_normal(ks[3], -2, 2, (E, ff, d)) *
                  (1.0 / ff ** 0.5)).astype(dt),
     }
@@ -49,8 +72,13 @@ def capacity(cfg: ModelConfig, n_tokens: int) -> int:
     return max(8, -(-c // 8) * 8)        # round up to 8 for tiling
 
 
-def apply(params: dict, x: Array, cfg: ModelConfig) -> tuple[Array, dict]:
+def apply(params: dict, x: Array, cfg: ModelConfig,
+          experts: tuple | None = None) -> tuple[Array, dict]:
     """x: (B, S, d) -> (y, aux_losses).
+
+    ``experts``, optional, is ``(stack, layer)``: this block's expert
+    weights stacked over the layer scan, of which ``params`` holds slice
+    ``layer``; the dropless path's kernel reads them in the stack.
 
     ``cfg.moe_dispatch == "local"`` splits the tokens into
     ``moe_local_groups`` groups (aligned with the data-parallel shards)
@@ -63,6 +91,11 @@ def apply(params: dict, x: Array, cfg: ModelConfig) -> tuple[Array, dict]:
     B, S, d = x.shape
     T = B * S
     G = cfg.moe_local_groups
+    if cfg.n_held != cfg.n_experts and cfg.moe_dispatch != "global":
+        raise ValueError(
+            f"held_experts={cfg.held_experts} needs moe_dispatch 'global': "
+            f"the '{cfg.moe_dispatch}' dispatch routes into buffers of all "
+            f"{cfg.n_experts} experts")
     if cfg.moe_dispatch == "shard_map":
         y, aux = _apply_shard_map(params, x, cfg)
         if y is not None:
@@ -71,11 +104,10 @@ def apply(params: dict, x: Array, cfg: ModelConfig) -> tuple[Array, dict]:
     if cfg.moe_dispatch == "local" and G > 1 and T % G == 0:
         xg = x.reshape(G, T // G, d)
         C = capacity(cfg, T // G)
-        y, aux = jax.vmap(lambda xt: _dispatch_ffn(params, xt, cfg, C))(xg)
+        y, aux = jax.vmap(lambda xt: _capacity_ffn(params, xt, cfg, C))(xg)
         return (y.reshape(B, S, d),
                 jax.tree.map(lambda a: a.mean(0), aux))
-    C = capacity(cfg, T)
-    y, aux = _dispatch_ffn(params, x.reshape(T, d), cfg, C)
+    y, aux = _dispatch_ffn(params, x.reshape(T, d), cfg, experts)
     return y.reshape(B, S, d), aux
 
 
@@ -141,7 +173,7 @@ def _apply_shard_map(params: dict, x: Array, cfg: ModelConfig):
                                         capacity(cfg, Tm), "model")
             y = jax.lax.all_gather(y_m, "model", axis=0, tiled=True)
         else:
-            y, aux = _dispatch_ffn(p, xt, cfg, C)
+            y, aux = _capacity_ffn(p, xt, cfg, C)
             y = jax.lax.psum(y, "model")      # ff-contraction partials
         aux = jax.tree.map(lambda a: jax.lax.pmean(a, dax), aux)
         return y.reshape(Bl, Sl, dl), aux
@@ -150,18 +182,77 @@ def _apply_shard_map(params: dict, x: Array, cfg: ModelConfig):
                          out_specs=out_specs, check_vma=False)(params, x)
 
 
+def _router(params: dict, xt: Array, cfg: ModelConfig):
+    """(T, d) -> router logits and probabilities (T, E) in float32, and
+    the top-k gates, renormalised, with their expert ids (T, K)."""
+    logits = jnp.dot(xt, params["router"],
+                     preferred_element_type=jnp.float32)     # (T, E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, expert_idx = jax.lax.top_k(probs, cfg.experts_per_token)
+    gate_vals = gate_vals / jnp.maximum(
+        gate_vals.sum(-1, keepdims=True), 1e-9)
+    return logits, probs, gate_vals, expert_idx
+
+
+def _aux(logits, probs, flat_e, cfg: ModelConfig, kept) -> dict:
+    E = cfg.n_experts
+    fe = jnp.bincount(flat_e, length=E) / flat_e.shape[0]
+    return {"load_balance": (E * jnp.sum(probs.mean(0) * fe)
+                             ).astype(jnp.float32),
+            "router_z": jnp.mean(
+                jax.nn.logsumexp(logits, -1) ** 2).astype(jnp.float32),
+            "dropped_frac": 1.0 - kept}
+
+
+def _dispatch_ffn(params: dict, xt: Array, cfg: ModelConfig,
+                  experts: tuple | None = None) -> tuple[Array, dict]:
+    """Dropless route + held experts + combine on (T, d) tokens: the
+    pairs routed to a held expert, sorted by expert, run through grouped
+    matmuls over a buffer of T*K rows (enough for any routing); each
+    token then sums its held pairs' outputs times their gates in float32.
+    Buffer rows past the held pairs are never read."""
+    T, d = xt.shape
+    K, H = cfg.experts_per_token, cfg.n_held
+    with jax.named_scope("moe_route"):
+        logits, probs, gate_vals, expert_idx = _router(params, xt, cfg)
+        flat_e = expert_idx.reshape(-1)                      # (T*K,)
+        local = flat_e - cfg.expert_offset
+        held = (local >= 0) & (local < H)
+        local = jnp.where(held, local, H)
+        order = jnp.argsort(local, stable=True)              # held first
+        sizes = (local[:, None] == jnp.arange(H)).sum(0, dtype=jnp.int32)
+        h = xt[order // K]                                   # (T*K, d)
+
+    def stacked(first, second=None):
+        if experts is None:
+            return None
+        stack, layer = experts
+        return (stack[first], stack[second] if second else None,
+                layer)
+    with jax.named_scope("moe_experts"):
+        if cfg.mlp_type == "swiglu":
+            a = gmm(h, params["gate"], sizes, params["up"],
+                    stacked=stacked("gate", "up"))
+        else:
+            a = jax.nn.gelu(gmm(h, params["up"], sizes,
+                                stacked=stacked("up")))
+        out = gmm(a, params["down"], sizes, stacked=stacked("down"))
+    with jax.named_scope("moe_route"):
+        slot = jnp.zeros((T * K,), jnp.int32).at[order].set(
+            jnp.arange(T * K, dtype=jnp.int32),
+            unique_indices=True)                             # pair -> row
+        back = jnp.where(held[:, None], out[slot], 0).astype(jnp.float32)
+        y = (back.reshape(T, K, d) * gate_vals[..., None]).sum(1)
+    return y.astype(xt.dtype), _aux(logits, probs, flat_e, cfg, 1.0)
+
+
 def _route(params: dict, xt: Array, cfg: ModelConfig, C: int):
     """Sort-based capacity routing: tokens -> (E, C, d) expert buffers.
 
     Returns (buffers h, combine-state dict, aux losses)."""
     T, d = xt.shape
     E, K = cfg.n_experts, cfg.experts_per_token
-
-    logits = (xt @ params["router"]).astype(jnp.float32)     # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, K)          # (T, K)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    logits, probs, gate_vals, expert_idx = _router(params, xt, cfg)
 
     # flatten the K assignments, sort by expert id (stable => FIFO rank)
     flat_e = expert_idx.reshape(-1)                          # (T*K,)
@@ -179,13 +270,7 @@ def _route(params: dict, xt: Array, cfg: ModelConfig, C: int):
 
     buf = jnp.zeros((E * C + 1, d), xt.dtype).at[slot].set(xt[st])
     h = buf[: E * C].reshape(E, C, d)
-
-    me = probs.mean(0)                                       # (E,)
-    fe = jnp.bincount(flat_e, length=E) / (T * K)
-    aux = {"load_balance": (E * jnp.sum(me * fe)).astype(jnp.float32),
-           "router_z": jnp.mean(
-               jax.nn.logsumexp(logits, -1) ** 2).astype(jnp.float32),
-           "dropped_frac": 1.0 - keep.mean()}
+    aux = _aux(logits, probs, flat_e, cfg, keep.mean())
     state = {"st": st, "sg": sg, "keep": keep, "slot": slot, "T": T}
     return h, state, aux
 
@@ -211,10 +296,10 @@ def _combine(out: Array, state: dict, dtype) -> Array:
         gathered * sg[:, None].astype(dtype))
 
 
-def _dispatch_ffn(params: dict, xt: Array, cfg: ModelConfig,
+def _capacity_ffn(params: dict, xt: Array, cfg: ModelConfig,
                   C: int) -> tuple[Array, dict]:
-    """Route + expert FFN + combine on (T, d) tokens (single device /
-    tensor-parallel weight shards)."""
+    """Capacity route + expert FFN + combine on (T, d) tokens (the
+    dry-run's per-group and tensor-parallel dispatches)."""
     h, state, aux = _route(params, xt, cfg, C)
     out = _expert_ffn(params, h, cfg)
     return _combine(out, state, xt.dtype), aux
