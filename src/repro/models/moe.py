@@ -18,8 +18,13 @@ the SwiGLU's gate and up in one, then down) whose work follows the
 pairs, so a token's output never depends on the other tokens of its
 batch.  Given the layer's place in the stacked weights of a layer scan
 (``experts``), the kernel reads the weights there rather than from a
-per-layer copy.  Named scopes
-``moe_route`` (router, top-k, sort, gathers, combine) and
+per-layer copy.  The buffer has T*K rows, enough for any routing, but
+only its first ``sum(sizes)`` rows, the held pairs', are read.  A layer
+that holds a share of the experts (``n_held < n_experts``) fills and
+reads only those rows, on a TPU, in ``kernels/moe_route``'s
+``moe_dispatch`` and ``moe_combine``; a layer that holds every expert,
+where every row is live, keeps XLA's gathers over the whole buffer.
+Named scopes ``moe_route`` (router, top-k, sort, dispatch, combine) and
 ``moe_experts`` (the grouped matmuls) split it inside the block's
 ``mlp`` scope.
 
@@ -40,6 +45,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import moe_route as route
 from repro.kernels.moe_gmm import gmm
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init
@@ -210,9 +216,21 @@ def _dispatch_ffn(params: dict, xt: Array, cfg: ModelConfig,
     pairs routed to a held expert, sorted by expert, run through grouped
     matmuls over a buffer of T*K rows (enough for any routing); each
     token then sums its held pairs' outputs times their gates in float32.
-    Buffer rows past the held pairs are never read."""
+    Buffer rows past the held pairs are never read.
+
+    Which rows move: a layer that holds every expert fills and reads the
+    whole buffer with XLA's gathers.  A layer that holds a share
+    (``n_held < n_experts``) moves only the held pairs' rows, on a TPU,
+    with the ``kernels/moe_route`` kernels: the dispatch writes the first
+    ``sum(sizes)`` rows and the combine reads them back.  The kernels hold
+    the tokens in VMEM as float32, so they take at most 48 MiB / (8 d)
+    tokens in bfloat16 (3,072 at d 2048, 12 rows of 256 positions); a
+    larger batch falls back to XLA's gathers.  ``held_share`` in the
+    returned aux is the share of the T*K pairs that are held."""
     T, d = xt.shape
     K, H = cfg.experts_per_token, cfg.n_held
+    share = cfg.n_held < cfg.n_experts
+    moved = share and route.use_kernel(xt)
     with jax.named_scope("moe_route"):
         logits, probs, gate_vals, expert_idx = _router(params, xt, cfg)
         flat_e = expert_idx.reshape(-1)                      # (T*K,)
@@ -221,7 +239,11 @@ def _dispatch_ffn(params: dict, xt: Array, cfg: ModelConfig,
         local = jnp.where(held, local, H)
         order = jnp.argsort(local, stable=True)              # held first
         sizes = (local[:, None] == jnp.arange(H)).sum(0, dtype=jnp.int32)
-        h = xt[order // K]                                   # (T*K, d)
+        if moved:
+            count = sizes.sum()                              # held pairs
+            h = route.dispatch(xt, order, count, K)
+        else:
+            h = route.dispatch_ref(xt, order, K)             # (T*K, d)
 
     def stacked(first, second=None):
         if experts is None:
@@ -238,12 +260,16 @@ def _dispatch_ffn(params: dict, xt: Array, cfg: ModelConfig,
                                 stacked=stacked("up")))
         out = gmm(a, params["down"], sizes, stacked=stacked("down"))
     with jax.named_scope("moe_route"):
-        slot = jnp.zeros((T * K,), jnp.int32).at[order].set(
-            jnp.arange(T * K, dtype=jnp.int32),
-            unique_indices=True)                             # pair -> row
-        back = jnp.where(held[:, None], out[slot], 0).astype(jnp.float32)
-        y = (back.reshape(T, K, d) * gate_vals[..., None]).sum(1)
-    return y.astype(xt.dtype), _aux(logits, probs, flat_e, cfg, 1.0)
+        if moved:
+            y = route.combine(out, gate_vals, order, held, count,
+                              xt.dtype)
+        else:
+            y = route.combine_ref(out, route.slots(order), held,
+                                  gate_vals, xt.dtype)
+    aux = _aux(logits, probs, flat_e, cfg, 1.0)
+    aux["held_share"] = (held.mean(dtype=jnp.float32) if share
+                         else jnp.float32(1.0))
+    return y, aux
 
 
 def _route(params: dict, xt: Array, cfg: ModelConfig, C: int):

@@ -23,6 +23,7 @@ from repro.kernels.dndm_update import ops as dndm_ops
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.moe_gmm import ops as gmm_ops
 from repro.kernels.moe_gmm.kernel import moe_gmm
+from repro.kernels.moe_route import ops as route_ops
 
 B, N = 8, 256
 
@@ -153,6 +154,26 @@ def test_moe_gmm_compiles_at_sdar_widths(shape, k, n, swiglu):
     assert "%moe_gmm" in compiled.as_text()
 
 
+@pytest.mark.parametrize("op", ["moe_dispatch", "moe_combine"])
+def test_moe_route_compiles_at_sdar_widths(shape, op):
+    """The routing's row movement at SDAR-30B-A3B's widths on one chip:
+    batch 8 x canvas 256 tokens of width 2048, top-8, so a buffer of
+    16,384 pairs of which the 16 held experts' come first.  Each op
+    carries its name, which must not be the grouped matmul's."""
+    T, k, d = B * N, 8, 2048
+    pairs = shape((T * k,), jnp.int32)
+    count = shape((), jnp.int32)
+    if op == "moe_dispatch":
+        compiled = _compile(lambda x, o, n: route_ops.dispatch(
+            x, o, n, k, False), shape((T, d), jnp.bfloat16), pairs, count)
+    else:
+        compiled = _compile(lambda out, g, o, h, n: route_ops.combine(
+            out, g, o, h, n, jnp.bfloat16, False),
+            shape((T * k, d), jnp.bfloat16), shape((T, k), jnp.float32),
+            pairs, shape((T * k,), jnp.bool_), count)
+    assert f"%{op}" in compiled.as_text()
+
+
 @pytest.fixture
 def sdar_two_layers(shape, monkeypatch):
     """SDAR-30B-A3B at its widths with two layers: one chip's 16 held
@@ -162,6 +183,7 @@ def sdar_two_layers(shape, monkeypatch):
     from repro.models.model import Model
     monkeypatch.setattr(gmm_ops, "use_kernel", lambda: True)
     monkeypatch.setattr(gmm_ops, "default_interpret", lambda: False)
+    monkeypatch.setattr(route_ops, "default_interpret", lambda: False)
     cfg = configs.get("sdar-30b-a3b").replace(
         n_layers=2, block_pattern=moe_pattern(2), held_experts=16)
     model = Model(cfg)
@@ -186,9 +208,27 @@ def test_sdar_experts_read_in_place(shape, sdar_two_layers):
     assert copies == []
 
 
+def test_sdar_forward_moves_only_held_rows(shape, sdar_two_layers):
+    """The routing moves the held pairs' rows in its two kernels: no
+    gather or fusion of the compiled forward yields the (16384, 2048)
+    pair buffer (before, one filled it and one read it back, a fifth of
+    the step on the chip)."""
+    model, params = sdar_two_layers
+    compiled = _compile(
+        lambda p, x: model.forward(p, x, None, causal=False)[0],
+        params, shape((B, N), jnp.int32))
+    text = compiled.as_text()
+    assert "%moe_dispatch" in text and "%moe_combine" in text
+    whole = [line for line in text.splitlines()
+             if re.search(r"= bf16\[16384,2048\]\S* (gather|fusion)\(",
+                          line)]
+    assert whole == []
+
+
 def test_sdar_gradient_compiles(shape, sdar_two_layers):
-    """A training step's gradient through the kernel compiles for the
-    chip (the backward pass is ``ragged_dot``'s, by the custom VJP)."""
+    """A training step's gradient through the kernels compiles for the
+    chip (the backward passes are ``ragged_dot``'s and the XLA gather's
+    and combine's, by the custom VJPs)."""
     model, params = sdar_two_layers
 
     def loss(p, x):
@@ -198,4 +238,6 @@ def test_sdar_gradient_compiles(shape, sdar_two_layers):
 
     compiled = _compile(jax.value_and_grad(loss), params,
                         shape((2, 128), jnp.int32))
-    assert "%moe_gmm" in compiled.as_text()
+    text = compiled.as_text()
+    assert all(f"%{op}" in text
+               for op in ("moe_gmm", "moe_dispatch", "moe_combine"))
