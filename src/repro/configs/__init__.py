@@ -1,14 +1,10 @@
 """Config registry: ``--arch <id>`` ids -> ModelConfig factories.
 
 The ten assigned architectures (public-literature pool) plus the paper's
-own models.  ``for_long_context`` swaps full attention for sliding-window
-attention — the documented substitute that makes ``long_500k`` lowerable
-for otherwise-quadratic architectures (see DESIGN.md §2).
+own models.
 """
 from __future__ import annotations
 
-from repro.configs import shapes
-from repro.configs.shapes import SHAPES, InputShape
 from repro.models.config import ModelConfig
 
 _ARCH_MODULES = {
@@ -42,22 +38,4 @@ def list_archs() -> list[str]:
     return list(_ARCH_MODULES)
 
 
-LONG_CONTEXT_WINDOW = 4096
-
-
-def for_long_context(cfg: ModelConfig) -> ModelConfig:
-    """Sub-quadratic variant for long_500k decode.
-
-    SSM / hybrid / SWA architectures are already sub-quadratic; pure
-    full-attention blocks are swapped for sliding-window ("swa") blocks
-    with a 4k window (the documented dense-arch substitute).
-    """
-    pattern = tuple("swa" if k == "attn" else k for k in cfg.block_pattern)
-    window = cfg.sliding_window or LONG_CONTEXT_WINDOW
-    # shared_attn occurrences also become windowed via cfg.sliding_window?
-    # Zamba's shared attention keeps full span: its cache is seq-sharded.
-    return cfg.replace(block_pattern=pattern, sliding_window=window)
-
-
-__all__ = ["get", "list_archs", "ASSIGNED_ARCHS", "SHAPES", "InputShape",
-           "shapes", "for_long_context", "LONG_CONTEXT_WINDOW"]
+__all__ = ["get", "list_archs", "ASSIGNED_ARCHS"]

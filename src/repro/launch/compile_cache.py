@@ -1,8 +1,8 @@
 """JAX persistent compilation cache for the entry points.
 
-Every entry point (``chip_smoke.py``, ``repro.launch.serve``,
-``repro.launch.train``, ``benchmarks.run``) calls :func:`enable` before
-it compiles anything, so a second run of the same program on the same
+Every entry point (``perfbench/run.py``, ``repro.launch.serve``,
+``repro.launch.train``) calls :func:`enable` before it compiles
+anything, so a second run of the same program on the same
 machine reads its compiled executables back instead of compiling again.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this

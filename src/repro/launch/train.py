@@ -1,9 +1,8 @@
 """Distributed training launcher.
 
-On real hardware this builds the production mesh, shards params/optimizer
-with the rule system and runs the pjit train step; on a CPU it runs the
-same code path over however many devices exist (use launch/dryrun.py for
-the 512-device compile-only validation).
+It builds a (data, model) mesh over the devices there are, shards
+params/optimizer with the rule system (``launch/sharding.py``) and runs
+the pjit train step.
 
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --reduced --steps 20 --batch 8 --seq 128

@@ -1,7 +1,7 @@
 """Grouped-query attention with RoPE, sliding windows and KV caches.
 
 Three interchangeable inner implementations (``cfg.attn_impl``):
-  einsum  — naive S^2 attention (baseline for the roofline memory term)
+  einsum  — naive S^2 attention
   blocked — online-softmax over KV chunks in pure JAX (lax.scan); the
             memory-bounded TPU-shaped algorithm and the oracle for the
             Pallas flash kernel
@@ -86,13 +86,8 @@ def _einsum_attn(q, k, v, bias):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _blocked_attn(q, k, v, bias, block_k: int, unroll: bool = False):
-    """Online-softmax over KV chunks; O(S * block_k) live memory.
-
-    ``unroll=True`` runs the chunk loop as straight-line code instead of
-    ``lax.scan`` — used by the dry-run so XLA cost analysis counts every
-    chunk (scan bodies are costed once).
-    """
+def _blocked_attn(q, k, v, bias, block_k: int):
+    """Online-softmax over KV chunks; O(S * block_k) live memory."""
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     bk = min(block_k, Sk)
@@ -123,13 +118,7 @@ def _blocked_attn(q, k, v, bias, block_k: int, unroll: bool = False):
     m0 = jnp.full((B, H, Sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, H, Sq), jnp.float32)
     a0 = jnp.zeros((B, Sq, H, hd), jnp.float32)
-    if unroll:
-        carry = (m0, l0, a0)
-        for i in range(n_blocks):
-            carry, _ = body(carry, (kb[i], vb[i], biasb[i]))
-        m, l, acc = carry
-    else:
-        (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, biasb))
+    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, biasb))
     l = jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
     return (acc / l).astype(q.dtype)
 
@@ -139,8 +128,6 @@ def _inner(q, k, v, bias, cfg: ModelConfig):
     v = _repeat_kv(v, cfg.n_heads)
     if cfg.attn_impl == "blocked":
         return _blocked_attn(q, k, v, bias, cfg.attn_block_k)
-    if cfg.attn_impl == "blocked_unrolled":
-        return _blocked_attn(q, k, v, bias, cfg.attn_block_k, unroll=True)
     if cfg.attn_impl == "pallas":
         from repro.kernels.flash_attention import ops as flash_ops
         return flash_ops.flash_attention(

@@ -1,7 +1,7 @@
 """Block registry: init / full-sequence apply / cache init / decode step
 for every block kind, plus the residual wiring and pre-norms.
 
-Every block has the same external contract so the model can scan or unroll
+Every block has the same external contract so the model can scan
 heterogeneous patterns:
 
   init(key, cfg)                          -> params

@@ -11,8 +11,8 @@ A model is a sequence of *blocks* (``block_pattern``), each one of:
                   all its occurrences
 
 The pattern must be periodic (``pattern == unit * k``) so the layer stack
-can be run as a ``lax.scan`` over superblocks (weights stacked along the
-scan axis) or fully unrolled for dry-run cost analysis.
+runs as a ``lax.scan`` over superblocks (weights stacked along the scan
+axis).
 """
 from __future__ import annotations
 
@@ -56,11 +56,8 @@ class ModelConfig:
     experts_per_token: int = 0
     held_experts: int = 0                # experts this chip holds, 0 = all
     expert_offset: int = 0               # id of the first held expert
-    capacity_factor: float = 1.25
     router_z_weight: float = 1e-3
     load_balance_weight: float = 1e-2
-    moe_dispatch: str = "global"         # global | local (per-shard sort)
-    moe_local_groups: int = 16           # data-axis groups for "local"
 
     # ssm (mamba2)
     ssm_state: int = 64
@@ -72,7 +69,6 @@ class ModelConfig:
     # xlstm
     lstm_heads: int = 4
     mlstm_chunk: int = 0                 # 0 = full S^2 parallel form
-    mlstm_unroll: bool = False           # unroll the chunk loop (dry-run)
 
     # embeddings / head
     tie_embeddings: bool = False
@@ -89,7 +85,6 @@ class ModelConfig:
 
     # runtime / lowering
     dtype: str = "float32"
-    scan_layers: bool = True             # False => unroll (dry-run accuracy)
     remat: bool = False
     paper: str = ""                      # provenance note
 

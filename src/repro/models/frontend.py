@@ -3,8 +3,7 @@
 For [audio] (MusicGen) and [vlm] (Chameleon) we implement the language /
 decoder transformer only.  The conv codec (EnCodec) and the vision encoder
 (VQ tokenizer) are represented by precomputed embeddings of the correct
-shape, produced here (random projections of a seeded key at test time,
-``ShapeDtypeStruct`` placeholders in the dry-run).
+shape, produced here (random projections of a seeded key).
 
 ``frontend_embeds`` occupy the first ``cfg.frontend_tokens`` positions of
 the sequence (early fusion): the model overwrites its token embeddings at
@@ -25,15 +24,6 @@ def fake_frontend_embeds(key, cfg: ModelConfig, batch: int):
     return jax.random.normal(
         key, (batch, cfg.frontend_tokens, cfg.d_model),
         jnp.dtype(cfg.dtype)) * 0.02
-
-
-def frontend_spec(cfg: ModelConfig, batch: int, sharding=None):
-    """ShapeDtypeStruct for the dry-run input_specs()."""
-    if not cfg.frontend:
-        return None
-    return jax.ShapeDtypeStruct(
-        (batch, cfg.frontend_tokens, cfg.d_model), jnp.dtype(cfg.dtype),
-        sharding=sharding)
 
 
 def fuse(h, frontend_embeds):

@@ -1,4 +1,4 @@
-"""Top-level Model: embeddings, superblock stack (scan or unrolled),
+"""Top-level Model: embeddings, superblock stack (one layer scan),
 diffusion time conditioning, frontend fusion, LM head, KV/state caches.
 
 The forward pass names its parts with ``jax.named_scope``:
@@ -9,16 +9,13 @@ its part of the denoiser.
 
 The block pattern is decomposed into ``unit * n_super`` (config enforces
 periodicity).  Non-shared block weights are stacked along a leading
-``n_super`` axis and the stack runs as one ``lax.scan`` (fast compiles) or
-fully unrolled (``scan_layers=False`` — accurate dry-run cost analysis).
+``n_super`` axis and the stack runs as one ``lax.scan``.
 ``shared_attn`` blocks hold a single weight set used by every occurrence
 (Zamba-style), while each occurrence gets its own cache slot.  A ``moe``
 block is also handed its expert weights' whole stack and the layer's
 index, so its grouped-matmul kernel reads them in place (``moe.apply``).
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -81,8 +78,6 @@ class Model:
                 h = h + time_embed(params["time"], t, cfg.d_model)[:, None]
         h = frontend.fuse(h, frontend_embeds)
 
-        has_moe = "moe" in self.unit
-
         def superblock(h, unit_slice, layer=None):
             aux_tot = jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
             lb, rz = aux_tot
@@ -104,18 +99,11 @@ class Model:
         if cfg.remat:
             body = jax.checkpoint(superblock)
 
-        if cfg.scan_layers:
-            xs = (params["unit"],)
-            if has_moe:                  # and each layer's index
-                xs += (jnp.arange(self.n_super, dtype=jnp.int32),)
-            h, (lbs, rzs) = jax.lax.scan(lambda h, xs: body(h, *xs), h, xs)
-            lb, rz = lbs.sum(), rzs.sum()
-        else:
-            lb = rz = jnp.zeros((), jnp.float32)
-            for j in range(self.n_super):
-                sl = jax.tree.map(lambda x: x[j], params["unit"])
-                h, (lb_j, rz_j) = body(h, sl, j if has_moe else None)
-                lb, rz = lb + lb_j, rz + rz_j
+        xs = (params["unit"],)
+        if "moe" in self.unit:           # and each layer's index
+            xs += (jnp.arange(self.n_super, dtype=jnp.int32),)
+        h, (lbs, rzs) = jax.lax.scan(lambda h, xs: body(h, *xs), h, xs)
+        lb, rz = lbs.sum(), rzs.sum()
 
         with jax.named_scope("lm_head"):
             h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
@@ -173,20 +161,8 @@ class Model:
                     kind, p, h, cache_slice[f"b{i}"], pos, cfg)
             return h, new_cache
 
-        if cfg.scan_layers:
-            unit_wo_shared = params["unit"]
-            # shared params are closed over; scan consumes (params, cache)
-            def body(h, xs):
-                return superblock(h, xs)
-            h, new_cache = jax.lax.scan(body, h, (unit_wo_shared, cache))
-        else:
-            outs = []
-            for j in range(self.n_super):
-                psl = jax.tree.map(lambda x: x[j], params["unit"])
-                csl = jax.tree.map(lambda x: x[j], cache)
-                h, nc = superblock(h, (psl, csl))
-                outs.append(nc)
-            new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        # shared params are closed over; scan consumes (params, cache)
+        h, new_cache = jax.lax.scan(superblock, h, (params["unit"], cache))
 
         h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
         logits = h @ (params["embed"].T if cfg.tie_embeddings

@@ -6,10 +6,6 @@ mLSTM trains in the attention-like parallel form (one S^2 pass with the
 cumulative-forget decay matrix) and decodes with the exact (dh x dh)
 matrix-memory recurrence.  sLSTM is a genuine per-step recurrence
 (lax.scan over time) with block-diagonal recurrent weights per head.
-
-NOTE (roofline): the sLSTM time scan is sequential; XLA cost analysis
-counts its body once, so dry-run FLOPs for sLSTM layers are corrected
-analytically (see EXPERIMENTS §Roofline).
 """
 from __future__ import annotations
 
@@ -59,7 +55,7 @@ def _mlstm_parallel(q, k, v, logi, logf):
     return h
 
 
-def _mlstm_chunked(q, k, v, logi, logf, chunk: int, unroll: bool):
+def _mlstm_chunked(q, k, v, logi, logf, chunk: int):
     """Chunkwise-parallel mLSTM: within-chunk quadratic D-matrix,
     cross-chunk (C, n, m) matrix-memory carry.  O(S*L) memory instead of
     O(S^2) — the §Perf fix for the mLSTM prefill memory wall; exactly
@@ -117,15 +113,7 @@ def _mlstm_chunked(q, k, v, logi, logf, chunk: int, unroll: bool):
     C0 = jnp.zeros((B, nh, dh, dh), jnp.float32)
     n0 = jnp.zeros((B, nh, dh), jnp.float32)
     m0 = jnp.full((B, nh), -1e9, jnp.float32)
-    if unroll:
-        carry = (C0, n0, m0)
-        hs = []
-        for c in range(nc):
-            carry, h = body(carry, (qc[c], kc[c], vc[c], ic[c], fc[c]))
-            hs.append(h)
-        h = jnp.stack(hs, 0)
-    else:
-        _, h = jax.lax.scan(body, (C0, n0, m0), (qc, kc, vc, ic, fc))
+    _, h = jax.lax.scan(body, (C0, n0, m0), (qc, kc, vc, ic, fc))
     h = h.swapaxes(0, 1).reshape(B, nc * L, nh, dh)[:, :S]
     return h
 
@@ -149,8 +137,7 @@ def mlstm_apply(params: dict, u: Array, cfg: ModelConfig, *,
         logf = -jax.nn.softplus(-f_raw[:, :, 0])          # log sigmoid
         qf, kf, vf = (a.astype(jnp.float32) for a in (q, k, v))
         if cfg.mlstm_chunk and cfg.mlstm_chunk < S:
-            h = _mlstm_chunked(qf, kf, vf, logi, logf, cfg.mlstm_chunk,
-                               cfg.mlstm_unroll)
+            h = _mlstm_chunked(qf, kf, vf, logi, logf, cfg.mlstm_chunk)
         else:
             h = _mlstm_parallel(qf, kf, vf, logi, logf)
         h = h.reshape(B, S, d_in).astype(u.dtype)

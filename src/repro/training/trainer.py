@@ -1,8 +1,8 @@
 """Training loop: diffusion-denoiser objective + AdamW, jit/pjit-ready.
 
 ``make_train_step`` builds the canonical train step used everywhere:
-unit tests (1 device), the example drivers, and the multi-pod dry-run
-(jitted with NamedShardings over the production mesh).  Conditional
+unit tests (1 device), the example drivers, and ``launch/train.py``
+(jitted with NamedShardings over its mesh).  Conditional
 batches carry a clean source prefix; only target positions are corrupted
 and scored (the paper's MT setup with a decoder-only early-fusion twist).
 """
@@ -39,8 +39,7 @@ def make_train_step(model: Model, schedule: Schedule, noise: NoiseDist,
     ``microbatches > 1`` = gradient accumulation: the batch is split
     along dim 0 and gradients are averaged over an *unrolled* loop (the
     accumulation dependency chain keeps the live activation set to one
-    microbatch — the memory-fit lever for the big MoE trains; unrolled
-    rather than scanned so dry-run cost analysis stays exact).
+    microbatch — the memory-fit lever for the big MoE trains).
     """
     cfg = model.cfg
 

@@ -1,5 +1,4 @@
-"""Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see 1 device
-(the 512-device trick is exclusive to launch/dryrun.py)."""
+"""Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see 1 device."""
 import jax
 import pytest
 

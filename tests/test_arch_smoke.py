@@ -105,11 +105,3 @@ def test_full_config_matches_assignment(arch):
     if arch in ("musicgen-large", "chameleon-34b"):
         assert cfg.frontend is not None and cfg.frontend_tokens > 0
 
-
-def test_long_context_variant_subquadratic():
-    for arch in ARCHS:
-        cfg = C.for_long_context(C.get(arch))
-        assert "attn" not in cfg.block_pattern, arch
-        assert cfg.sliding_window > 0 or all(
-            k in ("mamba2", "mlstm", "slstm", "shared_attn")
-            for k in cfg.block_pattern)

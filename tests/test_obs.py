@@ -326,25 +326,6 @@ def test_schema_rejects_malformed_trace():
     assert len(schema.validate_trace_lines([ok])) == 1
 
 
-def test_schema_rejects_malformed_bench():
-    with pytest.raises(schema.SchemaError):
-        schema.validate_bench({"schema": 1})
-    good = {
-        "schema": 2, "jax_backend": "cpu", "quick": True,
-        "config": {"batch": 8, "seq": 32, "steps": 16},
-        "methods": {"dndm": {
-            "noise": "absorbing", "kind": "host", "wall_seconds": 0.1,
-            "compile_seconds": 0.0, "nfe": 10, "tokens_per_second": 100.0,
-            "us_per_nfe": 9.0,
-            "metrics": {"jit_cache_hits": 1, "jit_cache_misses": 1}}},
-        "telemetry": {"enabled": True, "trace": None, "metrics": {}},
-    }
-    schema.validate_bench(good)                  # no raise
-    bad = {**good, "methods": {}}
-    with pytest.raises(schema.SchemaError):
-        schema.validate_bench(bad)
-
-
 # ------------------------------------------------------------------
 # ISSUE 10: quantile sketches behind every histogram
 # ------------------------------------------------------------------
@@ -352,7 +333,7 @@ import json
 import threading
 import urllib.request
 
-from repro.obs import exporter, regress, slo
+from repro.obs import exporter, slo
 from repro.obs.sketch import DDSketch, quantile_of_snapshot
 
 
@@ -730,69 +711,6 @@ def test_scheduler_reports_completed_requests_to_slo(telemetry, tiny,
         budget="latency<1e+09", method="dndm_static") == 0
     assert obs.counter("scheduler.slo_breaches").value(
         budget="queue<0", method="dndm_static") == n
-
-
-# ------------------------------------------------------------------
-# ISSUE 10: bench-regression gate
-# ------------------------------------------------------------------
-
-def _serving_artifact(wall=10.0, rps=5.0, p95=0.4, nfe=100,
-                      parity=True, fewer=True):
-    mode = {"wall_seconds": wall, "throughput_rps": rps,
-            "latency_p50_s": p95 / 2, "latency_p95_s": p95,
-            "latency_p99_s": p95 * 1.2, "aggregate_nfe": nfe}
-    return {"schema": 2, "kind": "serving",
-            "modes": {"drain": dict(mode), "continuous": dict(mode)},
-            "comparison": {"solo_parity": parity, "fewer_nfe": fewer}}
-
-
-def test_regress_identical_and_improved_pass():
-    base = _serving_artifact()
-    ok, lines = regress.compare(base, _serving_artifact())
-    assert ok and not any(l.startswith("REGRESSION") for l in lines)
-    better = _serving_artifact(wall=5.0, rps=9.0, p95=0.2, nfe=50)
-    ok, _ = regress.compare(base, better)
-    assert ok                                # improvements never fail
-
-
-def test_regress_catches_wall_and_parity_regressions(tmp_path):
-    base = _serving_artifact()
-    ok, lines = regress.compare(base, _serving_artifact(wall=20.0))
-    assert not ok                            # 2x wall > 1.5x tolerance
-    assert any("wall_seconds" in l for l in lines
-               if l.startswith("REGRESSION"))
-    # parity flip is exact-match: fails at any magnitude
-    ok, lines = regress.compare(base, _serving_artifact(parity=False))
-    assert not ok
-    assert any("solo_parity" in l for l in lines
-               if l.startswith("REGRESSION"))
-    # noise inside tolerance passes
-    ok, _ = regress.compare(base, _serving_artifact(wall=13.0, rps=4.0))
-    assert ok
-    # CLI contract: 0 ok / 1 regression / 2 unreadable
-    b, n = tmp_path / "b.json", tmp_path / "n.json"
-    b.write_text(json.dumps(base))
-    n.write_text(json.dumps(_serving_artifact(wall=20.0)))
-    assert regress.main([str(b), str(b)]) == 0
-    assert regress.main([str(b), str(n)]) == 1
-    assert regress.main([str(b), str(n), "--wall-tol", "2.0"]) == 0
-    assert regress.main([str(b), str(tmp_path / "missing.json")]) == 2
-
-
-def test_regress_bench_kind_and_mismatched_kinds():
-    mk = lambda wall: {"schema": 2, "methods": {"dndm": {
-        "wall_seconds": wall, "tokens_per_second": 100.0, "nfe": 10}}}
-    ok, _ = regress.compare(mk(1.0), mk(1.2))
-    assert ok
-    ok, lines = regress.compare(mk(1.0), mk(3.0))
-    assert not ok
-    ok, lines = regress.compare(mk(1.0), _serving_artifact())
-    assert not ok and any("kind" in l for l in lines
-                          if l.startswith("REGRESSION"))
-    # a method missing from NEW is a regression
-    gone = {"schema": 2, "methods": {}}
-    ok, lines = regress.compare(mk(1.0), gone)
-    assert not ok
 
 
 def test_jax_profile_off_without_env(monkeypatch):
